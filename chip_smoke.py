@@ -1,0 +1,252 @@
+"""Bring-up smoke for the Hoard-fed trainer on a TPU v5e.
+
+    python chip_smoke.py               # one chip
+    python chip_smoke.py --four-chips  # the four-chip data-parallel phase only
+
+One chip (default): runs the trainer's entry point, ``repro.launch.train.main``,
+on full-width qwen1.5-0.5b at batch 8 x seq 512 for 6 steps, fed from real
+stripe files in the Hoard store, then
+
+* fails if the trainer restarted even once;
+* restores the final checkpoint with the trainer's template and checks every
+  leaf's dtype and shape against the manifest, and the bf16 params bit for bit;
+* recomputes the step-0 loss on the CPU backend from the same seed-0 params and
+  the loader's first batch: chip and CPU agree within a relative 2e-2.
+
+Four chips: the full-width sharded train step on a (data=4, model=1) mesh with
+ZeRO-sharded optimizer state, checked against the same global batch of 8 run
+on one chip (loss and grad-norm within 2e-2), then 3 steps at global batch 32
+with every param/opt leaf spanning 4 devices.
+
+The script refuses to run anywhere but a TPU.  It prints what it checked and
+the device's peak memory, never a step time; its last line on success is
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import sys
+import tempfile
+from pathlib import Path
+
+import jax
+import numpy as np
+
+sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
+
+from repro.configs import ARCHS, ShapeConfig  # noqa: E402
+from repro.data import TokenLoader  # noqa: E402
+from repro.launch import train  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import make_test_mesh  # noqa: E402
+from repro.launch.sharded_step import build_sharded_step  # noqa: E402
+from repro.models import build_model, params as PM  # noqa: E402
+from repro.train import (  # noqa: E402
+    AdamWConfig, CheckpointManager, SamplerState, init_opt_state, init_train_state,
+)
+
+ARCH = "qwen1.5-0.5b"
+FULL_WIDTH = True          # False only in CPU rehearsals of the phases
+SEED = 0
+BATCH, SEQ, STEPS = 8, 512, 6
+BIG_BATCH, BIG_STEPS = 32, 3
+RTOL = 2e-2
+
+
+class SmokeFailure(RuntimeError):
+    pass
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+
+
+def close(a: float, b: float) -> bool:
+    return math.isfinite(a) and math.isfinite(b) and abs(a - b) <= RTOL * abs(b)
+
+
+class CompileLog:
+    """Backend compile seconds (cache retrievals included) and cache hits."""
+
+    def __init__(self):
+        self.seconds, self.hits, self.misses = 0.0, 0, 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def report(self) -> str:
+        return (f"compile_s={self.seconds:.3f} persistent_cache_hits={self.hits} "
+                f"persistent_cache_misses={self.misses}")
+
+
+def model_config():
+    return ARCHS[ARCH] if FULL_WIDTH else ARCHS[ARCH].smoke()
+
+
+def first_batches(batch: int, work: Path, n: int = 1):
+    """The first ``n`` batches a fresh trainer's loader yields, as numpy."""
+    store, dspec, reader = train.stripe_token_corpus(
+        "train-corpus", model_config().vocab, batch=batch, seq=SEQ, seed=SEED,
+        data_root=str(work),
+    )
+    it = iter(TokenLoader(store, dspec, reader, batch=batch, state=SamplerState(seed=SEED)))
+    return [dict(zip(("tokens", "labels"), next(it))) for _ in range(n)]
+
+
+def peak_bytes(device) -> int:
+    return int((device.memory_stats() or {}).get("peak_bytes_in_use", 0))
+
+
+# --------------------------------------------------------------- one chip
+def one_chip(work: Path) -> None:
+    cfg = model_config()
+    model = build_model(cfg, mesh=None)
+    print(f"arch={ARCH} params={PM.param_count(model.layout())} "
+          f"batch x seq={BATCH} x {SEQ} dtype={cfg.dtype}")
+
+    ckpt_dir = work / "ckpt"
+    argv = ["--arch", ARCH, "--batch", str(BATCH), "--seq", str(SEQ),
+            "--steps", str(STEPS), "--seed", str(SEED),
+            "--ckpt-every", str(STEPS + 1), "--ckpt-dir", str(ckpt_dir),
+            "--data-root", str(work / "stripes")]
+    result = train.main(argv + (["--full-config"] if FULL_WIDTH else []))
+    print(f"losses={result.losses}")
+    print(f"peak_bytes_in_use={peak_bytes(jax.devices()[0])}")
+    print(f"memory_stats={jax.devices()[0].memory_stats()}")
+    check(result.restarts == 0, f"trainer restarted {result.restarts} time(s)")
+    check(result.final_step == STEPS and len(result.losses) == STEPS,
+          f"trainer stopped at step {result.final_step} with {len(result.losses)} losses")
+    check(all(math.isfinite(l) for l in result.losses), "non-finite training loss")
+
+    # ---- checkpoint: restore with the trainer's template ----------------
+    opt_cfg = AdamWConfig()
+    template = jax.eval_shape(lambda: dict(zip(
+        ("params", "opt"), init_train_state(model, jax.random.PRNGKey(SEED), opt_cfg))))
+    step, params, opt, _ = CheckpointManager(str(ckpt_dir)).restore(template=template)
+    check(step == STEPS, f"latest checkpoint is step {step}, want {STEPS}")
+    with open(ckpt_dir / f"step_{step:06d}" / "manifest.json") as fh:
+        manifest = json.load(fh)
+    restored = jax.tree.leaves({"params": params, "opt": opt})
+    wanted = jax.tree.leaves(template)
+    check(len(restored) == manifest["n_leaves"] == len(wanted), "checkpoint leaf count")
+    for i, (got, want) in enumerate(zip(restored, wanted)):
+        check(str(got.dtype) == manifest["leaf_dtypes"][i] == str(want.dtype),
+              f"leaf {i}: dtype {got.dtype}, manifest {manifest['leaf_dtypes'][i]}, "
+              f"template {want.dtype}")
+        check(list(got.shape) == manifest["leaf_shapes"][i] == list(want.shape),
+              f"leaf {i}: shape {got.shape}, manifest {manifest['leaf_shapes'][i]}")
+    final = jax.device_get(result.params)
+    n_bf16 = 0
+    for got, want in zip(jax.tree.leaves(params), jax.tree.leaves(final)):
+        want = np.asarray(want)
+        n_bf16 += str(want.dtype) == "bfloat16"
+        check(got.dtype == want.dtype and got.tobytes() == want.tobytes(),
+              "restored params differ from the trained ones")
+    check(n_bf16 > 0 or not FULL_WIDTH, "full-width params hold no bf16 leaf")
+    print(f"checkpoint: step {step}, {len(restored)} leaves match the manifest, "
+          f"params bit-identical ({n_bf16} bf16 leaves)")
+    loss_chip = result.losses[0]
+    del result, final, params, opt, restored
+
+    # ---- step-0 loss: chip (the trainer's) vs CPU backend ----------------
+    cpu = jax.devices("cpu")[0]
+    batch = first_batches(BATCH, work / "ref_stripes")[0]
+    p0 = jax.device_get(PM.materialize(model.layout(), jax.random.PRNGKey(SEED), cfg.dtype))
+    with jax.default_device(cpu):
+        loss_cpu, _ = jax.jit(model.loss)(jax.device_put(p0, cpu), jax.device_put(batch, cpu))
+    loss_cpu = float(loss_cpu)
+    print(f"reference step-0 loss: chip={loss_chip} cpu={loss_cpu}")
+    check(close(loss_chip, loss_cpu), f"chip step-0 loss {loss_chip} vs CPU {loss_cpu}")
+
+
+# ------------------------------------------------------------- four chips
+def four_chips(work: Path) -> None:
+    devices = jax.devices()
+    check(len(devices) >= 4, f"--four-chips needs 4 devices, found {len(devices)}")
+    cfg, opt_cfg = model_config(), AdamWConfig()
+    layout = build_model(cfg, mesh=None).layout()
+    print(f"arch={ARCH} params={PM.param_count(layout)} mesh=(data=4, model=1) "
+          f"global batch x seq={BATCH} x {SEQ}, then {BIG_BATCH} x {SEQ}")
+    # seed-0 params, held on the host so that both meshes start from the same bits
+    host_params = jax.device_get(PM.materialize(layout, jax.random.PRNGKey(SEED), cfg.dtype))
+    small = first_batches(BATCH, work / "small")[0]
+    big = first_batches(BIG_BATCH, work / "big", n=BIG_STEPS)
+
+    def setup(mesh, batch: int):
+        step = build_sharded_step(cfg, ShapeConfig("smoke", SEQ, batch, "train"), mesh, opt_cfg)
+        params = jax.device_put(host_params, step.param_sharding)
+        opt = jax.jit(lambda p: init_opt_state(p, opt_cfg), out_shardings=step.opt_sharding)(params)
+        return step, params, opt
+
+    def run(step, params, opt, batch):
+        params, opt, m = step.jitted(params, opt, jax.device_put(batch, step.batch_sharding))
+        return params, opt, float(m["loss"]), float(m["grad_norm"])
+
+    mesh4 = make_test_mesh(data=4, model=1, devices=devices[:4])
+    step, params, opt = setup(mesh4, BATCH)
+    params, opt, loss4, gnorm4 = run(step, params, opt, small)
+    print(f"4 chips, global batch {BATCH}: loss={loss4} grad_norm={gnorm4}")
+
+    step = build_sharded_step(cfg, ShapeConfig("smoke", SEQ, BIG_BATCH, "train"), mesh4, opt_cfg)
+    losses = []
+    for batch in big:
+        params, opt, loss, _ = run(step, params, opt, batch)
+        losses.append(loss)
+    print(f"4 chips, global batch {BIG_BATCH}: losses={losses}")
+    check(all(math.isfinite(l) for l in losses), "non-finite loss on 4 chips")
+    leaves = jax.tree.leaves((params, opt))
+    spans = {len(x.sharding.device_set) for x in leaves}
+    zero = sum(not x.sharding.is_fully_replicated for x in jax.tree.leaves(opt))
+    print(f"{len(leaves)} param/opt leaves span {sorted(spans)} devices; "
+          f"{zero} of {len(jax.tree.leaves(opt))} optimizer leaves are ZeRO-sharded")
+    check(spans == {4}, f"param/opt leaves span {sorted(spans)} devices, want 4")
+    check(zero > 0, "no optimizer leaf is sharded over the data axis")
+    peaks = [peak_bytes(d) for d in devices[:4]]
+    print(f"peak_bytes_in_use per device={peaks}")
+    print(f"memory_stats[0]={devices[0].memory_stats()}")
+    check(all(p > 0 for p in peaks), "a device reports no memory in use")
+    del params, opt, leaves
+
+    mesh1 = make_test_mesh(data=1, model=1, devices=devices[:1])
+    step, params, opt = setup(mesh1, BATCH)
+    _, _, loss1, gnorm1 = run(step, params, opt, small)
+    print(f"1 chip, same global batch {BATCH}: loss={loss1} grad_norm={gnorm1}")
+    check(close(loss4, loss1), f"loss: 4 chips {loss4} vs 1 chip {loss1}")
+    check(close(gnorm4, gnorm1), f"grad_norm: 4 chips {gnorm4} vs 1 chip {gnorm1}")
+
+
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--four-chips", action="store_true",
+                    help="run only the four-chip data-parallel phase")
+    args = ap.parse_args(argv)
+
+    cache_dir = enable_compile_cache()
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        sys.exit(f"chip_smoke: needs a TPU, JAX's first device is {device.platform}")
+    log = CompileLog()
+    print(f"device={device.device_kind} count={len(jax.devices())} compile_cache={cache_dir}")
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as work:
+        (four_chips if args.four_chips else one_chip)(Path(work))
+    print(log.report())
+    print(json.dumps({"ok": True, "device": {
+        "platform": device.platform, "kind": device.device_kind, "count": len(jax.devices()),
+    }}))
+
+
+if __name__ == "__main__":
+    main()

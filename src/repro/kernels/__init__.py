@@ -1,12 +1,11 @@
 """Pallas TPU kernels (pl.pallas_call + BlockSpec VMEM tiling).
 
-Each kernel ships with a pure-jnp oracle in ``ref.py`` and a dispatching
-wrapper in ``ops.py`` (interpret mode off-TPU).  Validated by shape/dtype
-sweeps in ``tests/test_kernels.py``.
+Each kernel ships with a pure-jnp oracle in ``ref.py`` and takes an explicit
+``interpret`` argument (default off).  Validated by shape/dtype sweeps in
+``tests/test_kernels.py``, which pass ``interpret=True`` on the CPU.
 """
 
-from . import pallas_compat  # noqa: F401  (must precede kernel imports)
-from . import ops, ref
+from . import ref
 from .cost import (
     KernelCost,
     flash_attention_cost,
@@ -23,7 +22,7 @@ from .swiglu import swiglu_mlp
 
 __all__ = [
     "KernelCost", "decode_attention", "flash_attention",
-    "flash_attention_cost", "mlstm_scan", "mlstm_scan_cost", "ops", "ref",
+    "flash_attention_cost", "mlstm_scan", "mlstm_scan_cost", "ref",
     "rmsnorm", "ssd_scan_kernel", "ssd_scan_cost", "swiglu_cost",
     "swiglu_mlp",
 ]

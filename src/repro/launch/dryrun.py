@@ -1,10 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init); everything else follows.
+``main`` asks XLA for 512 virtual host devices before the first device call
+(jax locks the device count when the backend starts); importing this module
+changes no environment.
 
 For each cell we build abstract params/optimizer/batch (ShapeDtypeStructs,
 no allocation), jit the step with explicit in/out shardings on the
@@ -25,80 +23,31 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding
-from jax.sharding import PartitionSpec as P
-
 from ..configs import ALL_SHAPES, ARCHS, SHAPES, shape_applicable
-from ..models import build_model, params as PM
-from ..models.registry import input_specs, step_fn
+from ..models import params as PM
 from ..roofline.analysis import RooflineReport, model_flops
 from ..roofline.hlo_walk import analyze as hlo_analyze
-from ..train.optimizer import AdamWConfig, opt_state_specs
+from .compile_cache import enable_compile_cache
 from .mesh import make_production_mesh
+from .sharded_step import build_sharded_step
 
 HBM_PER_CHIP = 16e9          # TPU v5e
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "results", "dryrun")
 
 
-def abstract_opt_state(layout, opt_cfg: AdamWConfig):
-    """ShapeDtypeStruct opt state matching init_opt_state's structure."""
-    f32 = lambda i: jax.ShapeDtypeStruct(i.shape, jnp.float32)
-    is_info = lambda x: isinstance(x, PM.ParamInfo)
-    state = {
-        "mu": jax.tree.map(f32, layout, is_leaf=is_info),
-        "nu": jax.tree.map(f32, layout, is_leaf=is_info),
-        "count": jax.ShapeDtypeStruct((), jnp.int32),
-    }
-    if opt_cfg.master_fp32:
-        state["master"] = jax.tree.map(f32, layout, is_leaf=is_info)
-    return state
-
-
-def _named(mesh, spec_tree):
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s),
-        spec_tree,
-        is_leaf=lambda x: isinstance(x, P),
-    )
-
-
 def _compile_cell(cfg, shape, mesh):
-    model = build_model(cfg, mesh=mesh, model_axis=mesh.shape["model"])
-    layout = model.layout()
-    params_abs = PM.abstract(layout, cfg.dtype)
-    param_sh = _named(mesh, PM.specs(layout))
-    batch_abs, batch_spec = input_specs(cfg, shape, mesh=mesh, model=model)
-    batch_sh = _named(mesh, batch_spec)
-
+    step = build_sharded_step(cfg, shape, mesh)
     t0 = time.time()
-    if shape.kind == "train":
-        opt_cfg = AdamWConfig()
-        from ..train.step import make_train_step
-
-        train = make_train_step(model, opt_cfg)
-        opt_abs = abstract_opt_state(layout, opt_cfg)
-        opt_sh = _named(mesh, opt_state_specs(layout, mesh, opt_cfg))
-        jitted = jax.jit(
-            train,
-            in_shardings=(param_sh, opt_sh, batch_sh),
-            out_shardings=(param_sh, opt_sh, None),
-            donate_argnums=(0, 1),
-        )
-        lowered = jitted.lower(params_abs, opt_abs, batch_abs)
-    else:
-        fn = step_fn(cfg, shape, model=model)
-        jitted = jax.jit(fn, in_shardings=(param_sh, batch_sh))
-        lowered = jitted.lower(params_abs, batch_abs)
+    lowered = step.jitted.lower(*step.args)
     t_lower = time.time() - t0
     t0 = time.time()
     compiled = lowered.compile()
-    return layout, compiled, t_lower, time.time() - t0
+    return step.layout, compiled, t_lower, time.time() - t0
 
 
 def run_cell(
@@ -192,6 +141,10 @@ def run_cell(
 
 
 def main():
+    os.environ["XLA_FLAGS"] = " ".join(
+        [os.environ.get("XLA_FLAGS", ""), "--xla_force_host_platform_device_count=512"]
+    ).strip()
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=sorted(ARCHS))
     ap.add_argument("--shape", default=None, choices=sorted(SHAPES))

@@ -21,9 +21,11 @@ from ..core import build_cluster
 from ..data import TokenDatasetSpec, materialize_token_dataset
 from ..models import build_model, params as PM
 from ..serve import ServeConfig, ServingEngine
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=sorted(ARCHS))
     ap.add_argument("--requests", type=int, default=4)

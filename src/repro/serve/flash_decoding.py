@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 _NEG = -1e30
@@ -66,7 +65,7 @@ def make_flash_decode(mesh, axis: str = "model"):
             out = acc_tot / jnp.where(l_tot == 0, 1.0, l_tot)[..., None]
             return out.reshape(B, Hq, 1, hd).astype(v_shard.dtype)
 
-        return shard_map(
+        return jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(), P(None, None, axis, None), P(None, None, axis, None), P()),

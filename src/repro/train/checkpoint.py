@@ -31,6 +31,7 @@ from dataclasses import asdict, dataclass
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -136,8 +137,10 @@ class CheckpointManager:
         with open(os.path.join(path, "manifest.json")) as fh:
             manifest = json.load(fh)
         leaves = [
-            np.load(os.path.join(path, f"leaf_{i:05d}.npy"))
-            for i in range(manifest["n_leaves"])
+            _load_leaf(os.path.join(path, f"leaf_{i:05d}.npy"), dtype, shape)
+            for i, (dtype, shape) in enumerate(
+                zip(manifest["leaf_dtypes"], manifest["leaf_shapes"])
+            )
         ]
         if template is not None:
             _, treedef = jax.tree.flatten(template)
@@ -163,6 +166,23 @@ class CheckpointManager:
         )
         for s in steps[: -self.keep]:
             shutil.rmtree(self._step_dir(s), ignore_errors=True)
+
+
+def _load_leaf(path: str, dtype: str, shape: list) -> np.ndarray:
+    """Load one leaf as the dtype the manifest recorded.
+
+    ``np.save`` writes extension dtypes such as bfloat16 as raw void
+    (``|V2``); viewing the bytes as the recorded dtype restores them exactly.
+    """
+    leaf = np.load(path)
+    want = jnp.dtype(dtype)
+    if leaf.dtype != want:
+        if leaf.dtype.itemsize != want.itemsize:
+            raise ValueError(f"{path}: stored {leaf.dtype} cannot hold {want}")
+        leaf = leaf.view(want)
+    if list(leaf.shape) != list(shape):
+        raise ValueError(f"{path}: shape {list(leaf.shape)} != manifest {list(shape)}")
+    return leaf
 
 
 def config_digest(cfg) -> str:

@@ -97,10 +97,7 @@ def compiled_step_flops(model, batch, *, opt_cfg: Optional[AdamWConfig] = None,
     compiled = jax.jit(make_train_step(model, opt_cfg)).lower(
         params, opt_state, batch
     ).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):            # older jax returns [dict]
-        ca = ca[0] if ca else {}
-    return float(ca.get("flops", 0.0))
+    return float(compiled.cost_analysis().get("flops", 0.0))
 
 
 def compiled_step_costs(model, batch, *, opt_cfg: Optional[AdamWConfig] = None,
@@ -121,9 +118,6 @@ def compiled_step_costs(model, batch, *, opt_cfg: Optional[AdamWConfig] = None,
     compiled = jax.jit(make_train_step(model, opt_cfg)).lower(
         params, opt_state, batch
     ).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     out = hlo_walk.analyze(compiled.as_text())
-    out["xla_flops"] = float(ca.get("flops", 0.0))
+    out["xla_flops"] = float(compiled.cost_analysis().get("flops", 0.0))
     return out

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .optimizer import compress_int8, decompress_int8
@@ -36,7 +35,7 @@ def two_level_grad_sync(grads, errors, mesh, *, compress: bool = True):
             return jax.lax.pmean(g, tuple(axes))
 
         spec = P(*[None])
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda g: jax.tree.map(simple, g),
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), grads),),
@@ -62,7 +61,7 @@ def two_level_grad_sync(grads, errors, mesh, *, compress: bool = True):
             out_e.append(se)
         return jax.tree.unflatten(tdef, out_g), jax.tree.unflatten(tdef, out_e)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         sync_tree,
         mesh=mesh,
         in_specs=(

@@ -10,8 +10,6 @@ import pytest
 
 _ELASTIC = textwrap.dedent(
     """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json, tempfile
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -60,8 +58,6 @@ _ELASTIC = textwrap.dedent(
 
 _GRADSYNC = textwrap.dedent(
     """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp, numpy as np
     from repro.launch.mesh import make_test_mesh
@@ -86,7 +82,7 @@ _GRADSYNC = textwrap.dedent(
 
 def _run(script: str) -> dict:
     env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, timeout=600, env=env)

@@ -55,8 +55,6 @@ def test_ssd_scan_matches_sequential_recurrence():
 
 _FLASH_DECODE = textwrap.dedent(
     """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -89,7 +87,7 @@ def test_flash_decoding_sequence_parallel():
     """shard_map partial-softmax merge == full-softmax oracle, with a head
     count (10) that cannot shard the 4-way model axis."""
     env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run([sys.executable, "-c", _FLASH_DECODE],
                           capture_output=True, text=True, timeout=600, env=env)

@@ -11,44 +11,14 @@ import pytest
 
 _SCRIPT = textwrap.dedent(
     """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
-    import jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import ARCHS, ShapeConfig
-    from repro.models import build_model, params as PM
-    from repro.models.registry import input_specs
-    from repro.train.step import make_train_step
-    from repro.train.optimizer import AdamWConfig, opt_state_specs
-    from repro.launch.dryrun import abstract_opt_state, _named
     from repro.launch.mesh import make_test_mesh
+    from repro.launch.sharded_step import build_sharded_step
 
-    arch = %(arch)r
     mesh = make_test_mesh(data=2, model=2, pods=2)
-    cfg = ARCHS[arch].smoke()
-    shape = ShapeConfig("t", 128, 8, %(kind)r)
-    model = build_model(cfg, mesh=mesh, model_axis=2)
-    layout = model.layout()
-    params_abs = PM.abstract(layout, cfg.dtype)
-    param_sh = _named(mesh, PM.specs(layout))
-    batch_abs, batch_spec = input_specs(cfg, shape, mesh=mesh, model=model)
-    batch_sh = _named(mesh, batch_spec)
-    if shape.kind == "train":
-        opt_cfg = AdamWConfig()
-        step = make_train_step(model, opt_cfg)
-        opt_abs = abstract_opt_state(layout, opt_cfg)
-        opt_sh = _named(mesh, opt_state_specs(layout, mesh, opt_cfg))
-        c = jax.jit(step, in_shardings=(param_sh, opt_sh, batch_sh),
-                    out_shardings=(param_sh, opt_sh, None),
-                    donate_argnums=(0, 1)).lower(params_abs, opt_abs, batch_abs).compile()
-    else:
-        from repro.models.registry import step_fn
-        c = jax.jit(step_fn(cfg, shape, model=model),
-                    in_shardings=(param_sh, batch_sh)).lower(params_abs, batch_abs).compile()
-    cost = c.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax<=0.4 returns [dict], newer a dict
-        cost = cost[0] if cost else {}
+    step = build_sharded_step(ARCHS[%(arch)r].smoke(), ShapeConfig("t", 128, 8, %(kind)r), mesh)
+    cost = step.jitted.lower(*step.args).compile().cost_analysis() or {}
     print(json.dumps({"ok": True, "flops": cost.get("flops", 0.0)}))
     """
 )
@@ -56,7 +26,7 @@ _SCRIPT = textwrap.dedent(
 
 def _run(arch: str, kind: str):
     env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT % {"arch": arch, "kind": kind}],

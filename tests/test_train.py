@@ -98,6 +98,25 @@ def test_checkpoint_roundtrip_and_prune(tiny_setup, tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_checkpoint_restores_recorded_dtypes_bit_exact(tmp_path):
+    """bf16 leaves (saved by np.save as raw void) come back as bf16, bit for
+    bit, next to fp32 and int32 leaves."""
+    rng = np.random.default_rng(5)
+    params = {
+        "w": jnp.asarray(rng.normal(size=(7, 3)), jnp.bfloat16),
+        "b": jnp.asarray(rng.normal(size=(3,)), jnp.float32),
+    }
+    opt = {"mu": jnp.asarray(rng.normal(size=(7, 3)), jnp.float32),
+           "count": jnp.asarray(11, jnp.int32)}
+    ckpt = CheckpointManager(str(tmp_path), keep=1)
+    ckpt.save(4, params, opt, blocking=True)
+    _, p2, o2, _ = ckpt.restore(template={"params": params, "opt": opt})
+    for a, b in zip(jax.tree.leaves((params, opt)), jax.tree.leaves((p2, o2))):
+        a = np.asarray(a)
+        assert b.dtype == a.dtype and b.shape == a.shape
+        assert b.tobytes() == a.tobytes()
+
+
 def test_checkpoint_async_write(tiny_setup, tmp_path):
     cfg, model, opt_cfg, params, opt, batch = tiny_setup
     ckpt = CheckpointManager(str(tmp_path), keep=2, async_write=True)
@@ -160,3 +179,26 @@ def test_token_loader_resumable_deterministic(tmp_path):
     again = [next(it2)[0] for _ in range(3)]
     for a, b in zip(seen[3:], again):
         np.testing.assert_array_equal(a, b)
+
+
+def test_trainer_main_reports_losses_restarts_and_final_params(tmp_path, monkeypatch):
+    """The entry point returns what its caller needs to check a run: one
+    finite loss per step, zero restarts, and the params the last checkpoint
+    holds (bit for bit)."""
+    from repro.launch import train
+
+    # a set variable leaves JAX's cache config alone: no .jax_cache from tests
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
+    result = train.main([
+        "--steps", "3", "--batch", "2", "--seq", "32", "--ckpt-every", "4",
+        "--ckpt-dir", str(tmp_path / "ckpt"), "--data-root", str(tmp_path / "data"),
+    ])
+    assert result.final_step == 3 and result.restarts == 0
+    assert len(result.losses) == 3 and all(np.isfinite(result.losses))
+    model = build_model(ARCHS["qwen1.5-0.5b"].smoke(), mesh=None)
+    _, opt = init_train_state(model, KEY, AdamWConfig())
+    step, params, _, _ = CheckpointManager(str(tmp_path / "ckpt")).restore(
+        template={"params": result.params, "opt": opt})
+    assert step == 3
+    for a, b in zip(jax.tree.leaves(result.params), jax.tree.leaves(params)):
+        assert np.asarray(a).tobytes() == b.tobytes()
