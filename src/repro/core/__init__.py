@@ -10,7 +10,12 @@ Public API surface (see DESIGN.md for the paper mapping):
 * ``Rebalancer`` / ``MembershipEpoch``     — elastic membership + online re-striping
 * ``HoardLoader`` + backends               — transparent iterators (R4)
 * ``Telemetry`` / ``Tracer``               — flow spans, timelines, stall classes
+* ``HostSpans`` / ``hostspans``            — wall-clock spans + counters of real reads
 * ``run_scenario`` / ``build_cluster``     — one-call experiment harness
+
+The simulated paths need only numpy.  The real read path (``read_item`` on a
+materialized dataset, and so ``TokenLoader`` and HoardFS) also imports jax:
+each of its ``hostspans`` spans is a ``jax.profiler.TraceAnnotation``.
 """
 
 from .cache import (
@@ -40,6 +45,8 @@ from .loader import (
     StripeDataPlane,
     TrainingJob,
 )
+from . import hostspans
+from .hostspans import HostSpans
 from .metrics import ClusterMetrics, JobMetrics
 from .placement import JobSpec, Placement, PlacementEngine
 from .prefetch import FillTracker, PrefetchScheduler
@@ -91,7 +98,7 @@ __all__ = [
     "DatasetSpec", "DatasetStat", "Event", "EvictionPolicy",
     "FillTracker",
     "FlowTag",
-    "HoardBackend", "HoardLoader", "JobMetrics", "JobRecord", "JobResult",
+    "HoardBackend", "HoardLoader", "HostSpans", "JobMetrics", "JobRecord", "JobResult",
     "JobSpec", "LRUCache", "LRUStackModel", "LocalCopyBackend",
     "MANIFEST_SCHEMA_VERSION", "MembershipEpoch", "Node", "PAPER", "PagePool",
     "Placement", "PlacementEngine", "PrefetchScheduler", "ReadScheduler",
@@ -104,5 +111,5 @@ __all__ = [
     "WRITE_BACK", "WRITE_POLICIES",
     "WRITE_THROUGH", "WorkloadCalibration",
     "WorkloadJob", "WorkloadResult", "WritePlane", "buffer_cache_items",
-    "build_cluster", "rollup_stalls", "run_scenario", "stable_seed",
+    "build_cluster", "hostspans", "rollup_stalls", "run_scenario", "stable_seed",
 ]

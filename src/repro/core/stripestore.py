@@ -29,6 +29,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from . import hostspans
 from .readsched import ReadScheduler, stable_mix
 from .topology import Node, Topology
 
@@ -1050,17 +1051,22 @@ class StripeStore:
         return cand[np.arange(len(cand)), choice], choice, width
 
     def read_item(self, dataset_id: str, item: int, reader: Node) -> bytes:
-        """Real-bytes read (materialized mode) with CRC verification."""
+        """Real-bytes read (materialized mode) with CRC verification.
+
+        Spans ``stripe.locate``, ``stripe.io`` and ``stripe.verify`` and the
+        ``stripe.*`` counters (:mod:`repro.core.hostspans`) time and count it.
+        """
         man = self.manifests[dataset_id]
         if not man.materialized:
             raise StripeError("read_item on a non-materialized dataset")
         chunk = man.chunk_of_item(item)
+        off = (item - chunk * man.items_per_chunk) * man.item_bytes
         if not man.is_filled(chunk):
             if not man.chunk_nodes[chunk]:
                 # non-resident (partial caching): remote read-through — serve
                 # the remote store's copy without landing anything locally
                 blob = self.remote_payload(man, chunk)
-                off = (item - chunk * man.items_per_chunk) * man.item_bytes
+                hostspans.count("stripe.bytes_delivered", man.item_bytes)
                 return blob[off : off + man.item_bytes]
             raise StripeError(
                 f"{dataset_id} chunk {chunk} not filled yet (on-demand fill in progress)"
@@ -1070,9 +1076,10 @@ class StripeStore:
             # read-your-writes: the un-fsync'd overlay is the freshest image
             # (committed content + buffered writes applied); no CRC — the
             # checksum describes committed bytes only
-            off = (item - chunk * man.items_per_chunk) * man.item_bytes
+            hostspans.count("stripe.bytes_delivered", man.item_bytes)
             return bytes(pending.data[off : off + man.item_bytes])
-        src = self.locate(dataset_id, item, reader)
+        with hostspans.span("stripe.locate"):
+            src = self.locate(dataset_id, item, reader)
         try:
             blob = self._read_chunk(man, src.node_id, chunk)
         except (ChunkCorruption, FileNotFoundError):
@@ -1080,18 +1087,22 @@ class StripeStore:
             # verified path, which serves from a healthy copy AND rewrites
             # the bad replica in place — readers (HoardFS.pread included)
             # must never hard-fail while a healthy copy exists
+            hostspans.count("stripe.fallbacks")
             blob = self.read_chunk_verified(
                 dataset_id, chunk, reader, skip_replica=src.node_id
             )
-        off = (item - chunk * man.items_per_chunk) * man.item_bytes
+        hostspans.count("stripe.bytes_delivered", man.item_bytes)
         return blob[off : off + man.item_bytes]
 
     def _read_chunk(self, man: StripeManifest, node_id: int, chunk: int) -> bytes:
         path = self._chunk_path(man.dataset_id, node_id, chunk)
-        with open(path, "rb") as fh:
-            blob = fh.read()
-        if zlib.crc32(blob) != man.chunk_crc[chunk]:
-            raise ChunkCorruption(f"{man.dataset_id} chunk {chunk} on node {node_id}")
+        with hostspans.span("stripe.io"):
+            with open(path, "rb") as fh:
+                blob = fh.read()
+        hostspans.count("stripe.bytes_read", len(blob))
+        with hostspans.span("stripe.verify"):
+            if zlib.crc32(blob) != man.chunk_crc[chunk]:
+                raise ChunkCorruption(f"{man.dataset_id} chunk {chunk} on node {node_id}")
         return blob
 
     def read_chunk_verified(

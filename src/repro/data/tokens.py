@@ -6,7 +6,8 @@ and ``TokenLoader`` reads items through the stripe store (CRC-verified,
 closest replica) into device-ready (tokens, labels) batches.  The training
 loop sees a plain iterator — Requirement 4's transparency — and per-epoch
 order is a seeded permutation with resumable state (epoch, step), which the
-checkpoint manager persists for deterministic restart.
+checkpoint manager persists for deterministic restart.  Each batch is one
+``loader.batch`` span of :mod:`repro.core.hostspans`, over its item reads.
 """
 
 from __future__ import annotations
@@ -16,7 +17,7 @@ from typing import Iterator, Optional
 
 import numpy as np
 
-from ..core import CacheManager, DatasetSpec, Node, StripeStore
+from ..core import CacheManager, DatasetSpec, Node, StripeStore, hostspans
 from ..train.checkpoint import SamplerState
 
 
@@ -93,9 +94,11 @@ class TokenLoader:
             while self.state.step_in_epoch < steps:
                 s = self.state.step_in_epoch
                 ids = order[s * self.batch : (s + 1) * self.batch]
-                toks = np.stack([self._read_item(i) for i in ids])
-                self.state.step_in_epoch += 1
-                labels = np.concatenate([toks[:, 1:], toks[:, :1]], axis=1)
+                # closed before the yield: the consumer's time is not the batch's
+                with hostspans.batch():
+                    toks = np.stack([self._read_item(i) for i in ids])
+                    self.state.step_in_epoch += 1
+                    labels = np.concatenate([toks[:, 1:], toks[:, :1]], axis=1)
                 yield toks, labels
             self.state.epoch += 1
             self.state.step_in_epoch = 0

@@ -10,8 +10,20 @@ Wires every substrate together:
 
 ``main`` returns a :class:`TrainResult` (per-step losses, restart count and
 the final parameters) so that a caller such as ``chip_smoke.py`` can check
-the run.  The printed milliseconds are host time to read a batch and
-dispatch the step, not device step time.
+the run.  Every tenth step it prints the loss, the step time (the interval
+between two loss fetches, which wait for the device, over the steps between
+them) and, from :mod:`repro.core.hostspans`, the read path's locate, io and
+verify milliseconds per step, its read amplification and its replica
+fallbacks (a chunk replica that failed its CRC or is gone, read again from a
+healthy copy).  The straggler monitor watches each batch's ``loader.batch``
+time: a slow or failing stripe read is Hoard's straggler.
+
+The step's ops carry the named scopes ``embed``, ``attention``, ``mlp``,
+``logits_loss`` and ``optimizer``, which XProf's op profile and trace viewer
+group by.  JAX's persistent compile cache leaves op names out of its key, so
+an executable cached before the scopes existed is loaded without them: clear
+the cache directory (or set ``jax_compilation_cache_include_metadata_in_key``)
+before profiling a step that was cached by an older build.
 
 CPU-shaped by default (small mesh, smoke config); pass --full-config on a
 real fleet.  Usage:
@@ -33,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import ARCHS
-from ..core import Node, StripeStore, build_cluster
+from ..core import Node, StripeStore, build_cluster, hostspans
 from ..data import TokenDatasetSpec, TokenLoader, materialize_token_dataset
 from ..models import build_model
 from ..train import (
@@ -75,6 +87,22 @@ def stripe_token_corpus(
     )
     materialize_token_dataset(store, cache, dspec, topo.nodes[:4], items_per_chunk=16)
     return store, dspec, topo.nodes[0]
+
+
+def _timings(fetched: Optional[tuple[int, float]], step: int, now: float) -> str:
+    """Step time since the last loss fetch, and the read path over those steps."""
+    if fetched is None:
+        return ""
+    n = step - fetched[0]
+    out = f" step {(now - fetched[1]) / n * 1e3:.1f}ms"
+    recs = hostspans.last(n)
+    if recs is not None:
+        ms = [hostspans.per_batch_ms(recs, f"stripe.{s}") for s in ("locate", "io", "verify")]
+        fallbacks = sum(r.counters.get("stripe.fallbacks", 0) for r in recs)
+        out += (" read locate/io/verify {:.2f}/{:.2f}/{:.2f}ms".format(*ms)
+                + f" amplification {hostspans.read_amplification(recs):.0f}x"
+                + f" fallbacks {fallbacks}")
+    return out
 
 
 def main(argv=None) -> TrainResult:
@@ -123,21 +151,23 @@ def main(argv=None) -> TrainResult:
         losses = []
         it = iter(loader)
 
+        fetched = None                  # (step, time) of the last loss fetch
         with PreemptionGuard() as guard:
             for step in range(start, args.steps):
-                t0 = time.time()
                 toks, labels = next(it)
+                read_s = hostspans.last(1)[0].total_ns[hostspans.BATCH_SPAN] / 1e9
+                if monitor.record(read_s):
+                    print(f"[straggler] step {step} batch read took {read_s:.2f}s")
                 params, opt, metrics = step_fn(
                     params, opt, {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
                 )
-                dt = time.time() - t0
                 losses.append(metrics["loss"])
-                if monitor.record(dt):
-                    print(f"[straggler] step {step} took {dt:.2f}s")
                 if step % 10 == 0 or step == args.steps - 1:
-                    print(f"step {step:5d} loss={float(metrics['loss']):.4f} "
-                          f"gnorm={float(metrics['grad_norm']):.3f} "
-                          f"read+dispatch {dt*1000:.0f}ms")
+                    line = (f"step {step:5d} loss={float(metrics['loss']):.4f} "
+                            f"gnorm={float(metrics['grad_norm']):.3f}")
+                    now = time.perf_counter()
+                    print(line + _timings(fetched, step, now))
+                    fetched = (step, now)
                 if (step + 1) % args.ckpt_every == 0 or guard.should_stop:
                     ckpt.save(step + 1, params, opt, sampler=loader.state,
                               config_digest=digest)

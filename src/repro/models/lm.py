@@ -249,8 +249,10 @@ class DecoderLM:
     def _layer(self, p, x, positions, *, moe: bool):
         cfg = self.cfg
         window = cfg.sliding_window
-        x = self._attention(p["attn"], x, positions, window=window, pairs=cfg.causal_pairs)
-        x, aux = self._mlp(p["mlp"], x, moe=moe)
+        with jax.named_scope("attention"):
+            x = self._attention(p["attn"], x, positions, window=window, pairs=cfg.causal_pairs)
+        with jax.named_scope("mlp"):
+            x, aux = self._mlp(p["mlp"], x, moe=moe)
         x = self._shard(x, self._dp(), None, None)
         return x, aux
 
@@ -283,7 +285,8 @@ class DecoderLM:
         return rms_norm(x, params["final_ln"], cfg.norm_eps), aux_total
 
     def embed(self, params, tokens):
-        return params["embed"][tokens].astype(jnp.dtype(self.cfg.dtype))
+        with jax.named_scope("embed"):
+            return params["embed"][tokens].astype(jnp.dtype(self.cfg.dtype))
 
     def unembed(self, params, h):
         if self.cfg.tie_embeddings:
@@ -306,11 +309,12 @@ class DecoderLM:
         h, aux = self.backbone(params, x, positions)
         if n_img:
             h = h[:, n_img:]
-        logits = self.unembed(params, h).astype(jnp.float32)
-        logits = self._shard(logits, self._dp(), None, TP)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        nll = (lse - gold).mean()
+        with jax.named_scope("logits_loss"):
+            logits = self.unembed(params, h).astype(jnp.float32)
+            logits = self._shard(logits, self._dp(), None, TP)
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+            nll = (lse - gold).mean()
         total = nll + 0.01 * aux
         return total, {"nll": nll, "aux": aux}
 

@@ -26,7 +26,8 @@ def make_train_step(model, opt_cfg: Optional[AdamWConfig] = None):
             return loss, metrics
 
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        new_params, new_opt, opt_metrics = adamw_update(grads, opt_state, params, opt_cfg)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(grads, opt_state, params, opt_cfg)
         metrics = {**metrics, **opt_metrics, "loss": loss}
         return new_params, new_opt, metrics
 
