@@ -13,13 +13,21 @@ stripe files in the Hoard store, then
 * recomputes the step-0 loss on the CPU backend from the same seed-0 params and
   the loader's first batch: chip and CPU agree within a relative 2e-2.
 
+It then checks one qwen-width layer's causal attention on the chip at the
+benchmark's two shapes (2 x 2048 and 32 x 128): the Pallas flash kernel
+against the XLA blockwise path, loss and gradients within a relative 2e-2,
+with one timing line per shape (milliseconds of a forward and backward pass
+of each path, host clock around ``block_until_ready``), and prints how many
+attention calls each path has been lowered for.
+
 Four chips: the full-width sharded train step on a (data=4, model=1) mesh with
 ZeRO-sharded optimizer state, checked against the same global batch of 8 run
 on one chip (loss and grad-norm within 2e-2), then 3 steps at global batch 32
 with every param/opt leaf spanning 4 devices.
 
-The script refuses to run anywhere but a TPU.  It prints what it checked and
-the device's peak memory, never a step time; its last line on success is
+The script refuses to run anywhere but a TPU.  It prints what it checked,
+the device's peak memory and the attention timing lines, never a step time;
+its last line on success is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 """
 
@@ -30,9 +38,11 @@ import json
 import math
 import sys
 import tempfile
+import time
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
@@ -44,6 +54,9 @@ from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_test_mesh  # noqa: E402
 from repro.launch.sharded_step import build_sharded_step  # noqa: E402
 from repro.models import build_model, params as PM  # noqa: E402
+from repro.models.layers import (  # noqa: E402
+    attention, attention_path_tally, blockwise_attention, flash_causal_attention,
+)
 from repro.train import (  # noqa: E402
     AdamWConfig, CheckpointManager, SamplerState, init_opt_state, init_train_state,
 )
@@ -53,6 +66,8 @@ FULL_WIDTH = True          # False only in CPU rehearsals of the phases
 SEED = 0
 BATCH, SEQ, STEPS = 8, 512, 6
 BIG_BATCH, BIG_STEPS = 32, 3
+ATTN_SHAPES = ((2, 2048), (32, 128))   # batch x seq of the benchmark's two cells
+ATTN_REPEATS = 20
 RTOL = 2e-2
 
 
@@ -170,6 +185,52 @@ def one_chip(work: Path) -> None:
     loss_cpu = float(loss_cpu)
     print(f"reference step-0 loss: chip={loss_chip} cpu={loss_cpu}")
     check(close(loss_chip, loss_cpu), f"chip step-0 loss {loss_chip} vs CPU {loss_cpu}")
+    attention_check()
+
+
+# ------------------------------------------------------------- attention
+def _attention_grads(fn):
+    """jit of (loss, (dq, dk, dv)) of one attention call, loss = mean(o**2) in f32."""
+    loss = lambda q, k, v: jnp.mean(jnp.square(fn(q, k, v).astype(jnp.float32)))
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+
+
+def _ms_per_call(fn, *args) -> float:
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(ATTN_REPEATS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t) / ATTN_REPEATS * 1e3
+
+
+def attention_check() -> None:
+    """One layer's causal attention: the Pallas kernel against the XLA path."""
+    cfg = model_config()
+    H, hd = cfg.n_heads, cfg.resolved_head_dim
+    xla = _attention_grads(lambda q, k, v: blockwise_attention(
+        q, k, v, causal=True, q_block=cfg.q_block, kv_block=cfg.kv_block))
+    kernel = _attention_grads(flash_causal_attention)
+    dispatched = _attention_grads(lambda q, k, v: attention(q, k, v, causal=True))
+    for B, S in ATTN_SHAPES:
+        q, k, v = (jax.random.normal(key, (B, H, S, hd), jnp.bfloat16)
+                   for key in jax.random.split(jax.random.PRNGKey(SEED), 3))
+        (loss_k, grads_k), (loss_x, grads_x) = kernel(q, k, v), xla(q, k, v)
+        norms_k = [float(jnp.linalg.norm(g.astype(jnp.float32))) for g in grads_k]
+        norms_x = [float(jnp.linalg.norm(g.astype(jnp.float32))) for g in grads_x]
+        gaps = [float(jnp.linalg.norm((a.astype(jnp.float32) - b.astype(jnp.float32)))) / n
+                for a, b, n in zip(grads_k, grads_x, norms_x)]
+        print(f"attention {B} x {S}: loss kernel={float(loss_k)} xla={float(loss_x)}; "
+              f"grad norms q/k/v kernel={norms_k} xla={norms_x}; relative gaps {gaps}")
+        check(close(float(loss_k), float(loss_x)), f"attention {B} x {S}: loss")
+        check(all(g <= RTOL for g in gaps), f"attention {B} x {S}: gradients {gaps}")
+        before = attention_path_tally()
+        jax.block_until_ready(dispatched(q, k, v))
+        took = [p for p, n in attention_path_tally().items() if n > before[p]]
+        print(f"attention {B} x {S} timing: kernel {_ms_per_call(kernel, q, k, v):.3f} ms, "
+              f"xla {_ms_per_call(xla, q, k, v):.3f} ms per forward and backward; "
+              f"the dispatcher takes {took}")
+    print(f"attention paths lowered: {attention_path_tally()}")
 
 
 # ------------------------------------------------------------- four chips

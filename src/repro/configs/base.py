@@ -94,7 +94,6 @@ class ModelConfig:
     dtype: str = "bfloat16"
     q_block: int = 512                 # blockwise-attention tile sizes
     kv_block: int = 512
-    use_pallas: bool = False           # TPU kernels; XLA path for CPU dry-run
     remat: str = "dots"                # none | dots | full
     causal_pairs: bool = False         # triangle/banded block enumeration
                                        # (exact-FLOPs attention; perf feature)

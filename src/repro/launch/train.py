@@ -19,11 +19,14 @@ healthy copy).  The straggler monitor watches each batch's ``loader.batch``
 time: a slow or failing stripe read is Hoard's straggler.
 
 The step's ops carry the named scopes ``embed``, ``attention``, ``mlp``,
-``logits_loss`` and ``optimizer``, which XProf's op profile and trace viewer
+``logits_loss`` and ``optimizer`` (the attention kernel, where it runs, in
+``flash`` inside ``attention``), which XProf's op profile and trace viewer
 group by.  JAX's persistent compile cache leaves op names out of its key, so
 an executable cached before the scopes existed is loaded without them: clear
 the cache directory (or set ``jax_compilation_cache_include_metadata_in_key``)
-before profiling a step that was cached by an older build.
+before profiling a step that was cached by an older build.  The first step's
+line also prints how many attention calls were lowered to each path
+(``pallas_flash``, ``xla_blockwise``; see ``models/layers.py`` ``attention``).
 
 CPU-shaped by default (small mesh, smoke config); pass --full-config on a
 real fleet.  Usage:
@@ -48,6 +51,7 @@ from ..configs import ARCHS
 from ..core import Node, StripeStore, build_cluster, hostspans
 from ..data import TokenDatasetSpec, TokenLoader, materialize_token_dataset
 from ..models import build_model
+from ..models.layers import attention_path_tally
 from ..train import (
     AdamWConfig,
     CheckpointManager,
@@ -165,6 +169,9 @@ def main(argv=None) -> TrainResult:
                 if step % 10 == 0 or step == args.steps - 1:
                     line = (f"step {step:5d} loss={float(metrics['loss']):.4f} "
                             f"gnorm={float(metrics['grad_norm']):.3f}")
+                    if step == start:
+                        line += " attention " + " ".join(
+                            f"{k}={n}" for k, n in attention_path_tally().items())
                     now = time.perf_counter()
                     print(line + _timings(fetched, step, now))
                     fetched = (step, now)

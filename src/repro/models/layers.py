@@ -1,11 +1,20 @@
-"""Shared model primitives: norms, RoPE, blockwise attention, MLP, MoE.
+"""Shared model primitives: norms, RoPE, attention, MLP, MoE.
 
 Attention is implemented *blockwise with online softmax* (the flash pattern)
-in pure XLA so that (a) 32k/512k sequences fit memory without Pallas, (b) the
-same math is drop-in replaced by the Pallas kernel on TPU, and (c) the HLO is
-scan-shaped and stays small for the 512-device dry-run compile.
+in pure XLA so that (a) 32k/512k sequences fit memory without Pallas, and
+(b) the HLO is scan-shaped and stays small for the 512-device dry-run compile.
 
-Two block-enumeration modes:
+``attention`` is the one entry the models call.  It sends causal
+self-attention to a fused Pallas flash-attention kernel (the library's
+splash attention, forward and backward) when the program is lowered for a TPU and the call fits the kernel:
+no sliding window, no query offset, as many kv heads as query heads, equal q
+and v head sizes, equal q and kv lengths that are a multiple of 128 and at
+least ``FLASH_MIN_SEQ``, a head size up to 128 or a multiple of 128, and a
+model that is not partitioned over a mesh (GSPMD cannot split a Mosaic
+kernel).  Every other call, and every call lowered for another platform,
+runs ``blockwise_attention``.
+
+Two block-enumeration modes of ``blockwise_attention``:
 
 * rectangle (default): every (q-block, kv-block) pair is computed and masked.
   Simple, but causal masking wastes ~2x FLOPs at long sequence.
@@ -16,6 +25,7 @@ Two block-enumeration modes:
 
 from __future__ import annotations
 
+import collections
 import math
 from typing import Optional
 
@@ -23,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.extend import core as jex_core
+from jax.interpreters import ad, mlir
 
 _NEG_INF = -1e30
 
@@ -209,6 +221,120 @@ def blockwise_attention(
     out = a_all / jnp.where(l_all == 0, 1.0, l_all)[..., None]
     out = jnp.moveaxis(out, 0, 3).reshape(B, Hkv, G, Sq, hdv)
     return out.reshape(B, Hq, Sq, hdv)[:, :, :Sq0].astype(v.dtype)
+
+
+# ------------------------------------------------------- attention dispatch
+# Shortest sequence the kernel takes.  On a TPU v5e, one layer's attention
+# at 4,096 tokens (forward and backward under the step's remat policy) was
+# faster on the XLA path at S = 128 and 256 (one 512-block, no scan) and
+# faster on the kernel from S = 512 up (PERF.md, the S = 128 decision).
+FLASH_MIN_SEQ = 512
+ATTENTION_PATHS = ("pallas_flash", "xla_blockwise")
+# The kernel's output and softmax statistics carry this name, so that a remat
+# policy can keep them and the backward pass need not rerun the forward.
+FLASH_RESIDUALS = "flash_residuals"
+
+_path_tally: collections.Counter = collections.Counter()
+
+# An identity on the query that counts its path when it is lowered.
+# ``lax.platform_dependent`` traces both branches but lowers only the target
+# platform's, so a count taken while tracing would see both.  It marks q, not
+# the output: every pass needs q (the backward too), while an output that
+# only a gradient depends on is dead code and would drop the count.
+_path_p = jex_core.Primitive("attention_path")
+_path_p.def_impl(lambda x, *, path: x)
+_path_p.def_abstract_eval(lambda x, *, path: x)
+ad.primitive_jvps[_path_p] = (
+    lambda primals, tangents, *, path: (_path_p.bind(primals[0], path=path), tangents[0])
+)
+
+
+def _lower_path(ctx, x, *, path):
+    _path_tally[path] += 1
+    return [x]
+
+
+mlir.register_lowering(_path_p, _lower_path)
+
+
+def attention_path_tally() -> dict[str, int]:
+    """Attention calls lowered per path since the process started.
+
+    A call is counted each time a program holding it is lowered (once per
+    jit compile; a hit in the persistent compile cache is still lowered), so
+    a layer scan counts its body, not each layer.
+    """
+    return {p: _path_tally[p] for p in ATTENTION_PATHS}
+
+
+def _flash_fits(q, k, v, *, causal: bool, window: int, q_offset: int) -> bool:
+    """Whether the Pallas kernel computes this call: the same attention as
+    ``blockwise_attention``, up to bf16 rounding."""
+    B, Hq, Sq, hd = q.shape
+    _, Hkv, Skv, hdv = v.shape
+    return (
+        causal and window == 0 and q_offset == 0 and Hq == Hkv
+        and k.shape[-1] == hd == hdv and Sq == Skv
+        and Sq % 128 == 0 and Sq >= FLASH_MIN_SEQ
+        and (hd <= 128 or hd % 128 == 0)
+    )
+
+
+def _flash_block(seq: int) -> int:
+    """The kernel's tile for a sequence of ``seq``: the largest of 512, 256
+    and 128 that divides it (512 was fastest of the three in every pass at
+    S = 2048 on a TPU v5e, PERF.md)."""
+    return next(b for b in (512, 256, 128) if seq % b == 0)
+
+
+def flash_causal_attention(q, k, v):
+    """Causal self-attention by the Pallas splash-attention kernel
+    (``jax.experimental.pallas.ops.tpu.splash_attention``), forward and fused
+    backward.  q, k, v: (B, H, S, hd) in the activation dtype; scores,
+    softmax statistics and accumulators are float32.  TPU only (or under
+    ``pltpu.force_tpu_interpret_mode``); ``attention`` decides when.
+
+    Pallas is imported here, on the first trace, not with this module: it
+    takes about 1.5 s to import, which callers that never trace an
+    attention that fits should not pay."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    B, H, S, hd = q.shape
+    b = _flash_block(S)
+    kernel = splash.make_splash_mha_single_device(
+        splash.MultiHeadMask([splash.CausalMask((S, S))] * H),
+        block_sizes=splash.BlockSizes(
+            block_q=b, block_kv=b, block_q_dkv=b, block_kv_dkv=b, use_fused_bwd_kernel=True,
+        ),
+        residual_checkpoint_name=FLASH_RESIDUALS,
+    )
+    with jax.named_scope("flash"):
+        # the kernel takes one sequence's (H, S, hd) and no scale: scale q as
+        # blockwise_attention does, in the activation dtype
+        return jax.vmap(kernel)(q * hd ** -0.5, k, v)
+
+
+def attention(
+    q, k, v, *, causal: bool = True, window: int = 0, q_offset: int = 0,
+    partitioned: bool = False, **blockwise,
+):
+    """Attention by the path that fits: the Pallas kernel on a TPU where
+    ``_flash_fits`` and the model is not ``partitioned`` over a mesh, else
+    ``blockwise_attention`` (which takes the remaining keyword arguments)."""
+
+    def xla(q, k, v):
+        q = _path_p.bind(q, path="xla_blockwise")
+        return blockwise_attention(
+            q, k, v, causal=causal, window=window, q_offset=q_offset, **blockwise
+        )
+
+    if partitioned or not _flash_fits(q, k, v, causal=causal, window=window, q_offset=q_offset):
+        return xla(q, k, v)
+
+    def kernel(q, k, v):
+        return flash_causal_attention(_path_p.bind(q, path="pallas_flash"), k, v)
+
+    return lax.platform_dependent(q, k, v, tpu=kernel, default=xla)
 
 
 def decode_attention(q, k_cache, v_cache, valid_len, *, window: int = 0):
