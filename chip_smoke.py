@@ -20,10 +20,13 @@ with one timing line per shape (milliseconds of a forward and backward pass
 of each path, host clock around ``block_until_ready``), and prints how many
 attention calls each path has been lowered for.
 
-Four chips: the full-width sharded train step on a (data=4, model=1) mesh with
-ZeRO-sharded optimizer state, checked against the same global batch of 8 run
-on one chip (loss and grad-norm within 2e-2), then 3 steps at global batch 32
-with every param/opt leaf spanning 4 devices.
+Four chips: the trainer's entry point again, with ``--mesh data=4,model=1``
+(ZeRO data parallelism: params replicated, AdamW's state sharded over the four
+chips), at the same global batch of 8 x 512 for 6 steps.  It fails unless
+attention was lowered to the Pallas kernel alone, every param and optimizer
+leaf spans the four chips and some optimizer leaves are sharded, and it prints
+each chip's peak memory.  Then the same run on one chip: each step's loss
+within a relative 2e-2 of the four chips'.
 
 The script refuses to run anywhere but a TPU.  It prints what it checked,
 the device's peak memory and the attention timing lines, never a step time;
@@ -47,25 +50,22 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
-from repro.configs import ARCHS, ShapeConfig  # noqa: E402
+from repro.configs import ARCHS  # noqa: E402
 from repro.data import TokenLoader  # noqa: E402
 from repro.launch import train  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
-from repro.launch.mesh import make_test_mesh  # noqa: E402
-from repro.launch.sharded_step import build_sharded_step  # noqa: E402
 from repro.models import build_model, params as PM  # noqa: E402
 from repro.models.layers import (  # noqa: E402
     attention, attention_path_tally, blockwise_attention, flash_causal_attention,
 )
 from repro.train import (  # noqa: E402
-    AdamWConfig, CheckpointManager, SamplerState, init_opt_state, init_train_state,
+    AdamWConfig, CheckpointManager, SamplerState, init_train_state,
 )
 
 ARCH = "qwen1.5-0.5b"
 FULL_WIDTH = True          # False only in CPU rehearsals of the phases
 SEED = 0
 BATCH, SEQ, STEPS = 8, 512, 6
-BIG_BATCH, BIG_STEPS = 32, 3
 ATTN_SHAPES = ((2, 2048), (32, 128))   # batch x seq of the benchmark's two cells
 ATTN_REPEATS = 20
 RTOL = 2e-2
@@ -111,14 +111,14 @@ def model_config():
     return ARCHS[ARCH] if FULL_WIDTH else ARCHS[ARCH].smoke()
 
 
-def first_batches(batch: int, work: Path, n: int = 1):
-    """The first ``n`` batches a fresh trainer's loader yields, as numpy."""
+def first_batch(batch: int, work: Path) -> dict:
+    """The first batch a fresh trainer's loader yields, as numpy."""
     store, dspec, reader = train.stripe_token_corpus(
         "train-corpus", model_config().vocab, batch=batch, seq=SEQ, seed=SEED,
         data_root=str(work),
     )
     it = iter(TokenLoader(store, dspec, reader, batch=batch, state=SamplerState(seed=SEED)))
-    return [dict(zip(("tokens", "labels"), next(it))) for _ in range(n)]
+    return dict(zip(("tokens", "labels"), next(it)))
 
 
 def peak_bytes(device) -> int:
@@ -178,7 +178,7 @@ def one_chip(work: Path) -> None:
 
     # ---- step-0 loss: chip (the trainer's) vs CPU backend ----------------
     cpu = jax.devices("cpu")[0]
-    batch = first_batches(BATCH, work / "ref_stripes")[0]
+    batch = first_batch(BATCH, work / "ref_stripes")
     p0 = jax.device_get(PM.materialize(model.layout(), jax.random.PRNGKey(SEED), cfg.dtype))
     with jax.default_device(cpu):
         loss_cpu, _ = jax.jit(model.loss)(jax.device_put(p0, cpu), jax.device_put(batch, cpu))
@@ -237,56 +237,45 @@ def attention_check() -> None:
 def four_chips(work: Path) -> None:
     devices = jax.devices()
     check(len(devices) >= 4, f"--four-chips needs 4 devices, found {len(devices)}")
-    cfg, opt_cfg = model_config(), AdamWConfig()
-    layout = build_model(cfg, mesh=None).layout()
-    print(f"arch={ARCH} params={PM.param_count(layout)} mesh=(data=4, model=1) "
-          f"global batch x seq={BATCH} x {SEQ}, then {BIG_BATCH} x {SEQ}")
-    # seed-0 params, held on the host so that both meshes start from the same bits
-    host_params = jax.device_get(PM.materialize(layout, jax.random.PRNGKey(SEED), cfg.dtype))
-    small = first_batches(BATCH, work / "small")[0]
-    big = first_batches(BIG_BATCH, work / "big", n=BIG_STEPS)
+    print(f"arch={ARCH} mesh=(data=4, model=1) global batch x seq={BATCH} x {SEQ}, "
+          f"{STEPS} steps, against one chip at the same global batch")
 
-    def setup(mesh, batch: int):
-        step = build_sharded_step(cfg, ShapeConfig("smoke", SEQ, batch, "train"), mesh, opt_cfg)
-        params = jax.device_put(host_params, step.param_sharding)
-        opt = jax.jit(lambda p: init_opt_state(p, opt_cfg), out_shardings=step.opt_sharding)(params)
-        return step, params, opt
+    def trained(name: str, extra: list[str]) -> train.TrainResult:
+        argv = ["--arch", ARCH, "--batch", str(BATCH), "--seq", str(SEQ),
+                "--steps", str(STEPS), "--seed", str(SEED),
+                "--ckpt-every", str(STEPS + 1), "--ckpt-dir", str(work / name / "ckpt"),
+                "--data-root", str(work / name / "stripes")]
+        result = train.main(argv + (["--full-config"] if FULL_WIDTH else []) + extra)
+        check(result.restarts == 0, f"{name}: trainer restarted {result.restarts} time(s)")
+        check(len(result.losses) == STEPS and all(math.isfinite(l) for l in result.losses),
+              f"{name}: losses {result.losses}")
+        return result
 
-    def run(step, params, opt, batch):
-        params, opt, m = step.jitted(params, opt, jax.device_put(batch, step.batch_sharding))
-        return params, opt, float(m["loss"]), float(m["grad_norm"])
-
-    mesh4 = make_test_mesh(data=4, model=1, devices=devices[:4])
-    step, params, opt = setup(mesh4, BATCH)
-    params, opt, loss4, gnorm4 = run(step, params, opt, small)
-    print(f"4 chips, global batch {BATCH}: loss={loss4} grad_norm={gnorm4}")
-
-    step = build_sharded_step(cfg, ShapeConfig("smoke", SEQ, BIG_BATCH, "train"), mesh4, opt_cfg)
-    losses = []
-    for batch in big:
-        params, opt, loss, _ = run(step, params, opt, batch)
-        losses.append(loss)
-    print(f"4 chips, global batch {BIG_BATCH}: losses={losses}")
-    check(all(math.isfinite(l) for l in losses), "non-finite loss on 4 chips")
-    leaves = jax.tree.leaves((params, opt))
+    before = attention_path_tally()
+    four = trained("four", ["--mesh", "data=4,model=1"])
+    lowered = {p: n - before[p] for p, n in attention_path_tally().items()}
+    print(f"4 chips: losses={four.losses}; attention paths lowered {lowered}")
+    if devices[0].platform == "tpu":
+        check(lowered["pallas_flash"] > 0 and lowered["xla_blockwise"] == 0,
+              f"the mesh step's attention was lowered to {lowered}, want the kernel alone")
+    leaves = jax.tree.leaves((four.params, four.opt))
     spans = {len(x.sharding.device_set) for x in leaves}
-    zero = sum(not x.sharding.is_fully_replicated for x in jax.tree.leaves(opt))
+    zero = sum(not x.sharding.is_fully_replicated for x in jax.tree.leaves(four.opt))
     print(f"{len(leaves)} param/opt leaves span {sorted(spans)} devices; "
-          f"{zero} of {len(jax.tree.leaves(opt))} optimizer leaves are ZeRO-sharded")
+          f"{zero} of {len(jax.tree.leaves(four.opt))} optimizer leaves are ZeRO-sharded")
     check(spans == {4}, f"param/opt leaves span {sorted(spans)} devices, want 4")
     check(zero > 0, "no optimizer leaf is sharded over the data axis")
     peaks = [peak_bytes(d) for d in devices[:4]]
     print(f"peak_bytes_in_use per device={peaks}")
     print(f"memory_stats[0]={devices[0].memory_stats()}")
     check(all(p > 0 for p in peaks), "a device reports no memory in use")
-    del params, opt, leaves
+    losses4 = four.losses
+    del four, leaves
 
-    mesh1 = make_test_mesh(data=1, model=1, devices=devices[:1])
-    step, params, opt = setup(mesh1, BATCH)
-    _, _, loss1, gnorm1 = run(step, params, opt, small)
-    print(f"1 chip, same global batch {BATCH}: loss={loss1} grad_norm={gnorm1}")
-    check(close(loss4, loss1), f"loss: 4 chips {loss4} vs 1 chip {loss1}")
-    check(close(gnorm4, gnorm1), f"grad_norm: 4 chips {gnorm4} vs 1 chip {gnorm1}")
+    one = trained("one", [])
+    print(f"1 chip, same global batch {BATCH}: losses={one.losses}")
+    for step, (l4, l1) in enumerate(zip(losses4, one.losses)):
+        check(close(l4, l1), f"step {step} loss: 4 chips {l4} vs 1 chip {l1}")
 
 
 def main(argv=None) -> None:

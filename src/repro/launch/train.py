@@ -5,17 +5,26 @@ Wires every substrate together:
 * builds the cluster model (topology + stripe store + cache + placement),
 * materialises the seeded token corpus as real stripe files in the Hoard
   cache,
-* runs the jitted train step on the default device with async checkpoints,
-  preemption guard, straggler monitor and crash-restart.
+* runs the jitted train step with async checkpoints, preemption guard,
+  straggler monitor and crash-restart: on the default device, or with
+  ``--mesh`` on a mesh of this host's devices.
 
-``main`` returns a :class:`TrainResult` (per-step losses, restart count and
-the final parameters) so that a caller such as ``chip_smoke.py`` can check
-the run.  Every tenth step it prints the loss, the step time (the interval
-between two loss fetches, which wait for the device, over the steps between
-them) and, from :mod:`repro.core.hostspans`, the read path's locate, io and
-verify milliseconds per step, its read amplification and its replica
-fallbacks (a chunk replica that failed its CRC or is gone, read again from a
-healthy copy).  The straggler monitor watches each batch's ``loader.batch``
+``--mesh data=4,model=1`` (axes ``data`` and ``model``) builds the step
+with ``launch/sharded_step.py``'s ``build_sharded_step``, as the dry-run and
+the benchmark do: parameters laid out by the model's specs
+(replicated where ``model`` is 1), AdamW's state ZeRO-sharded over ``data``,
+and each batch placed by the step's batch sharding in one ``device_put``, so
+that each device receives only its own rows.  ``--batch`` is the global
+batch.  Checkpoints restore onto the same shardings.
+
+``main`` returns a :class:`TrainResult` (per-step losses, restart count, the
+final parameters and optimizer state) so that a caller such as
+``chip_smoke.py`` can check the run.  Every tenth step it prints the loss,
+the step time (the interval between two loss fetches, which wait for the
+device, over the steps between them) and, from :mod:`repro.core.hostspans`,
+the read path's locate, io and verify milliseconds per step, its read
+amplification and its replica fallbacks (a chunk replica that failed its CRC
+or is gone, read again from a healthy copy).  The straggler monitor watches each batch's ``loader.batch``
 time: a slow or failing stripe read is Hoard's straggler.
 
 The step's ops carry the named scopes ``embed``, ``attention``, ``mlp``,
@@ -28,11 +37,13 @@ before profiling a step that was cached by an older build.  The first step's
 line also prints how many attention calls were lowered to each path
 (``pallas_flash``, ``xla_blockwise``; see ``models/layers.py`` ``attention``).
 
-CPU-shaped by default (small mesh, smoke config); pass --full-config on a
-real fleet.  Usage:
+The smoke-sized model by default; ``--full-config`` for the architecture's
+published widths.  Usage:
 
     python -m repro.launch.train --arch qwen1.5-0.5b --steps 50 \
         --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+    python -m repro.launch.train --full-config --mesh data=4,model=1 \
+        --batch 8 --seq 2048 --steps 30
 """
 
 from __future__ import annotations
@@ -47,10 +58,11 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from ..configs import ARCHS
+from ..configs import ARCHS, ShapeConfig
 from ..core import Node, StripeStore, build_cluster, hostspans
 from ..data import TokenDatasetSpec, TokenLoader, materialize_token_dataset
 from ..models import build_model
+from ..models import params as PM
 from ..models.layers import attention_path_tally
 from ..train import (
     AdamWConfig,
@@ -59,11 +71,14 @@ from ..train import (
     SamplerState,
     StragglerMonitor,
     config_digest,
+    init_opt_state,
     init_train_state,
     make_train_step,
     run_with_restarts,
 )
 from .compile_cache import enable_compile_cache
+from .mesh import make_test_mesh
+from .sharded_step import build_sharded_step
 
 
 @dataclass
@@ -72,6 +87,7 @@ class TrainResult:
     restarts: int        # crash-restarts that run_with_restarts absorbed
     losses: list         # per-step losses of the attempt that finished
     params: Any          # final parameters, as the last checkpoint holds them
+    opt: Any = None      # final optimizer state, placed as the step left it
 
 
 def stripe_token_corpus(
@@ -109,12 +125,35 @@ def _timings(fetched: Optional[tuple[int, float]], step: int, now: float) -> str
     return out
 
 
+MESH_AXES = ("data", "model")
+
+
+def parse_mesh(text: str) -> dict[str, int]:
+    """``"data=4,model=1"`` -> ``{"data": 4, "model": 1}``."""
+    shape = {}
+    for part in text.split(","):
+        axis, _, n = part.partition("=")
+        if axis not in MESH_AXES or not n.isdigit() or int(n) < 1:
+            raise ValueError(f"--mesh {text!r}: want axis=size pairs over {MESH_AXES}")
+        shape[axis] = int(n)
+    return shape
+
+
+def build_mesh(shape: dict[str, int]):
+    """A (data, model) mesh of this host's first devices."""
+    data, model = shape.get("data", 1), shape.get("model", 1)
+    devices = jax.devices()
+    if len(devices) < data * model:
+        raise ValueError(f"--mesh {shape} needs {data * model} devices, found {len(devices)}")
+    return make_test_mesh(data=data, model=model, devices=devices[:data * model])
+
+
 def main(argv=None) -> TrainResult:
     enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=sorted(ARCHS))
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--batch", type=int, default=8, help="global batch")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None)
@@ -123,6 +162,9 @@ def main(argv=None) -> TrainResult:
     ap.add_argument("--data-root", default=None)
     ap.add_argument("--full-config", action="store_true",
                     help="use the full architecture (default: smoke config)")
+    ap.add_argument("--mesh", type=parse_mesh, default=None,
+                    help="train data-parallel on a mesh of this host's devices, "
+                         "e.g. data=4,model=1 (default: the default device alone)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -139,18 +181,42 @@ def main(argv=None) -> TrainResult:
     print(f"[hoard] dataset {args.dataset_id!r} striped over 4 nodes "
           f"({dspec.n_sequences} seqs x {args.seq} tokens)")
 
-    model = build_model(cfg, mesh=None)
+    # ---- the step, its state and its batches: one device or a mesh --------
+    if args.mesh is None:
+        model = build_model(cfg, mesh=None)
+        step_fn = jax.jit(make_train_step(model, opt_cfg), donate_argnums=(0, 1))
+
+        def init_state(key):
+            return init_train_state(model, key, opt_cfg)
+
+        def put(toks, labels):
+            return {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
+    else:
+        mesh = build_mesh(args.mesh)
+        st = build_sharded_step(cfg, ShapeConfig("train", args.seq, args.batch, "train"), mesh,
+                                opt_cfg)
+        step_fn = st.jitted
+        init_opt = jax.jit(lambda p: init_opt_state(p, opt_cfg), out_shardings=st.opt_sharding)
+        print(f"[mesh] {dict(mesh.shape)}, global batch {args.batch}")
+
+        def init_state(key):
+            params = jax.device_put(PM.materialize(st.layout, key, cfg.dtype), st.param_sharding)
+            return params, init_opt(params)
+
+        def put(toks, labels):
+            return jax.device_put({"tokens": toks, "labels": labels}, st.batch_sharding)
     digest = config_digest(cfg)
 
     def loop(resume) -> TrainResult:
-        params, opt = init_train_state(model, jax.random.PRNGKey(args.seed), opt_cfg)
+        params, opt = init_state(jax.random.PRNGKey(args.seed))
         sampler = SamplerState(seed=args.seed)
         start = 0
         if resume is not None and ckpt.latest_step() is not None:
-            start, params, opt, sampler = ckpt.restore(template={"params": params, "opt": opt})
+            state = {"params": params, "opt": opt}
+            start, params, opt, sampler = ckpt.restore(
+                template=state, shardings=jax.tree.map(lambda x: x.sharding, state))
             print(f"[restore] resumed from step {start}")
         loader = TokenLoader(store, dspec, reader, batch=args.batch, state=sampler)
-        step_fn = jax.jit(make_train_step(model, opt_cfg), donate_argnums=(0, 1))
         monitor = StragglerMonitor()
         losses = []
         it = iter(loader)
@@ -162,9 +228,7 @@ def main(argv=None) -> TrainResult:
                 read_s = hostspans.last(1)[0].total_ns[hostspans.BATCH_SPAN] / 1e9
                 if monitor.record(read_s):
                     print(f"[straggler] step {step} batch read took {read_s:.2f}s")
-                params, opt, metrics = step_fn(
-                    params, opt, {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
-                )
+                params, opt, metrics = step_fn(params, opt, put(toks, labels))
                 losses.append(metrics["loss"])
                 if step % 10 == 0 or step == args.steps - 1:
                     line = (f"step {step:5d} loss={float(metrics['loss']):.4f} "
@@ -177,13 +241,13 @@ def main(argv=None) -> TrainResult:
                     fetched = (step, now)
                 if (step + 1) % args.ckpt_every == 0 or guard.should_stop:
                     ckpt.save(step + 1, params, opt, sampler=loader.state,
-                              config_digest=digest)
+                              config_digest=digest, mesh_shape=args.mesh)
                 if guard.should_stop:
                     print("[preempt] checkpointed and exiting")
                     break
         ckpt.save(args.steps, params, opt, sampler=loader.state,
-                  config_digest=digest, blocking=True)
-        return TrainResult(args.steps, 0, [float(l) for l in losses], params)
+                  config_digest=digest, mesh_shape=args.mesh, blocking=True)
+        return TrainResult(args.steps, 0, [float(l) for l in losses], params, opt)
 
     restarts = []
 

@@ -9,10 +9,12 @@ self-attention to a fused Pallas flash-attention kernel (the library's
 splash attention, forward and backward) when the program is lowered for a TPU and the call fits the kernel:
 no sliding window, no query offset, as many kv heads as query heads, equal q
 and v head sizes, equal q and kv lengths that are a multiple of 128 and at
-least ``FLASH_MIN_SEQ``, a head size up to 128 or a multiple of 128, and a
-model that is not partitioned over a mesh (GSPMD cannot split a Mosaic
-kernel).  Every other call, and every call lowered for another platform,
-runs ``blockwise_attention``.
+least ``FLASH_MIN_SEQ``, and a head size up to 128 or a multiple of 128.  On
+a mesh the kernel runs inside ``jax.shard_map`` (GSPMD cannot split a Mosaic
+kernel): each device takes its own rows, split over the data axes, and its
+own heads, split over ``model``, and the call fits only where both divide.
+Every other call, and every call lowered for another platform, runs
+``blockwise_attention``.
 
 Two block-enumeration modes of ``blockwise_attention``:
 
@@ -27,6 +29,7 @@ from __future__ import annotations
 
 import collections
 import math
+from functools import partial
 from typing import Optional
 
 import jax
@@ -35,6 +38,7 @@ import numpy as np
 from jax import lax
 from jax.extend import core as jex_core
 from jax.interpreters import ad, mlir
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30
 
@@ -314,13 +318,34 @@ def flash_causal_attention(q, k, v):
         return jax.vmap(kernel)(q * hd ** -0.5, k, v)
 
 
+def _mesh_spec(mesh, q):
+    """The shard_map spec of q, k and v on ``mesh`` (rows over the data axes,
+    heads over ``model``), or None where a device's share does not divide."""
+    data = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    model = "model" if "model" in mesh.axis_names else None
+    rows = math.prod(mesh.shape[a] for a in data)
+    heads = mesh.shape[model] if model else 1
+    if q.shape[0] % rows or q.shape[1] % heads:
+        return None
+    return P(data or None, model, None, None)
+
+
+def sharded_flash_attention(q, k, v, mesh):
+    """``flash_causal_attention`` on every device of ``mesh``, each on its own
+    rows and heads; q, k, v must divide as ``_mesh_spec`` requires."""
+    spec = _mesh_spec(mesh, q)
+    return jax.shard_map(flash_causal_attention, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def attention(
-    q, k, v, *, causal: bool = True, window: int = 0, q_offset: int = 0,
-    partitioned: bool = False, **blockwise,
+    q, k, v, *, causal: bool = True, window: int = 0, q_offset: int = 0, mesh=None,
+    **blockwise,
 ):
     """Attention by the path that fits: the Pallas kernel on a TPU where
-    ``_flash_fits`` and the model is not ``partitioned`` over a mesh, else
-    ``blockwise_attention`` (which takes the remaining keyword arguments)."""
+    ``_flash_fits`` (and, on the model's ``mesh``, where each device's share
+    divides), else ``blockwise_attention`` (which takes the remaining keyword
+    arguments)."""
 
     def xla(q, k, v):
         q = _path_p.bind(q, path="xla_blockwise")
@@ -328,11 +353,13 @@ def attention(
             q, k, v, causal=causal, window=window, q_offset=q_offset, **blockwise
         )
 
-    if partitioned or not _flash_fits(q, k, v, causal=causal, window=window, q_offset=q_offset):
+    if (not _flash_fits(q, k, v, causal=causal, window=window, q_offset=q_offset)
+            or (mesh is not None and _mesh_spec(mesh, q) is None)):
         return xla(q, k, v)
+    flash = flash_causal_attention if mesh is None else partial(sharded_flash_attention, mesh=mesh)
 
     def kernel(q, k, v):
-        return flash_causal_attention(_path_p.bind(q, path="pallas_flash"), k, v)
+        return flash(_path_p.bind(q, path="pallas_flash"), k, v)
 
     return lax.platform_dependent(q, k, v, tpu=kernel, default=xla)
 

@@ -188,7 +188,7 @@ class DecoderLM:
             k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, H, S, m.qk_rope_dim))], -1)
             q = jnp.concatenate([q_nope, q_rope], -1)
             out = attention(
-                q, k, v, causal=True, window=window, partitioned=self.mesh is not None,
+                q, k, v, causal=True, window=window, mesh=self.mesh,
                 q_block=cfg.q_block, kv_block=cfg.kv_block, pairs=pairs,
                 mask_mode=cfg.mask_mode,
             )
@@ -207,7 +207,7 @@ class DecoderLM:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
         out = attention(
-            q, k, v, causal=True, window=window, partitioned=self.mesh is not None,
+            q, k, v, causal=True, window=window, mesh=self.mesh,
             q_block=cfg.q_block, kv_block=cfg.kv_block, pairs=pairs,
             mask_mode=cfg.mask_mode,
         )

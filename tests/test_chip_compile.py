@@ -24,13 +24,15 @@ from repro.configs import ARCHS, ShapeConfig
 from repro.launch.mesh import make_test_mesh
 from repro.launch.sharded_step import abstract_opt_state, build_sharded_step
 from repro.models import build_model, params as PM
+from repro.models.layers import attention_path_tally
 from repro.train import AdamWConfig, make_train_step
 
 ROOT = Path(__file__).resolve().parents[1]
 CFG = ARCHS["qwen1.5-0.5b"]
 HBM_BYTES = 16 * 2**30           # one TPU v5e chip
 BATCH, SEQ = 8, 512              # chip_smoke's one-chip run
-FOUR_CHIP_BATCH = 32             # chip_smoke's four-chip run (global)
+FOUR_CHIP_BATCH = 32             # a global batch of 8 per chip
+DP4_BATCH, DP4_SEQ = 8, 2048     # the four-chip benchmark cell, qwen05b-dp4-seq2048
 
 
 @pytest.fixture(scope="module")
@@ -90,6 +92,22 @@ def test_data_parallel_step_compiles_on_four_chips(topo, one_chip_memory):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     hlo = compiled.as_text()
     assert "all-reduce" in hlo or "reduce-scatter" in hlo    # gradients cross chips
+
+
+def test_data_parallel_step_takes_the_kernel_on_four_chips(topo):
+    """``train.py --mesh data=4,model=1`` at the four-chip cell's 8 x 2048:
+    attention runs the Pallas kernel under shard_map, two rows per chip, and
+    the step fits one chip's memory."""
+    mesh = make_test_mesh(data=4, model=1, devices=topo.devices)
+    step = build_sharded_step(CFG, ShapeConfig("dp4", DP4_SEQ, DP4_BATCH, "train"), mesh)
+    before = attention_path_tally()
+    lowered = step.jitted.lower(*step.args)
+    paths = {p: n - before[p] for p, n in attention_path_tally().items()}
+    assert paths == {"pallas_flash": 1, "xla_blockwise": 0}
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()                # per device
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert compiled.as_text().count("tpu_custom_call") >= 2    # forward and fused backward
 
 
 def test_chip_smoke_refuses_the_cpu():
