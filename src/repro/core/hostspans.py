@@ -135,7 +135,7 @@ def per_batch_ms(records: list[BatchRecord], name: str) -> float:
 
 
 def read_amplification(records: list[BatchRecord]) -> Optional[float]:
-    """Chunk bytes read from disk over item bytes delivered; ``None`` if none delivered."""
+    """Bytes read from disk over item bytes delivered; ``None`` if none delivered."""
     read = sum(r.counters.get("stripe.bytes_read", 0) for r in records)
     delivered = sum(r.counters.get("stripe.bytes_delivered", 0) for r in records)
     return read / delivered if delivered else None

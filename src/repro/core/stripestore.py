@@ -20,6 +20,7 @@ reader can locate the chunk (and the best replica) for any item index.
 
 from __future__ import annotations
 
+import base64
 import json
 import os
 import shutil
@@ -48,8 +49,16 @@ class StripeError(RuntimeError):
 # ``chunk_dirty``, the write-back mask for the bidirectional data plane: a
 # dirty chunk holds committed (fsync'd) writes that have not yet been flushed
 # to the remote store; pre-write-path blobs load with an empty (all-clean)
-# mask.
-MANIFEST_SCHEMA_VERSION = 4
+# mask.  v5 adds ``crc_block_bytes`` and ``chunk_block_crcs``, the per-block
+# CRC32 table that lets ``read_item`` verify only the blocks it reads; v1-v4
+# blobs load with an empty table, and their reads check whole chunks.
+MANIFEST_SCHEMA_VERSION = 5
+
+# Block size of the per-block CRC table a new manifest records.  Readers take
+# the size from the manifest, never from here.  An item read reads and checks
+# the blocks that cover it: one 64 KiB block for a 512-byte item of a 4 MiB
+# chunk, where a whole-chunk check would read the chunk.
+CRC_BLOCK_BYTES = 64 * 1024
 
 
 class ChunkCorruption(StripeError):
@@ -77,6 +86,12 @@ class StripeManifest:
     # write-back state (schema v4): chunk holds committed writes not yet
     # flushed to remote; empty list (pre-write-path manifests) = all clean
     chunk_dirty: list[bool] = field(default_factory=list)
+    # per-block CRCs (schema v5): chunk -> the CRC32 of each crc_block_bytes
+    # block of its blob (the last may be short), packed little-endian uint32
+    # and base64-encoded; "" = no table (never filled, or written before v5),
+    # and an empty list (v1-v4 manifests) = no tables at all
+    crc_block_bytes: int = CRC_BLOCK_BYTES
+    chunk_block_crcs: list[str] = field(default_factory=list)
 
     def is_filled(self, chunk: int) -> bool:
         return not self.chunk_filled or self.chunk_filled[chunk]
@@ -122,6 +137,25 @@ class StripeManifest:
     def chunk_of_item(self, item: int) -> int:
         return item // self.items_per_chunk
 
+    def seal_chunk(self, chunk: int, blob: bytes) -> None:
+        """Record the CRCs of the blob about to be written as ``chunk``.
+
+        The one place that sets ``chunk_crc``, and the block table with it,
+        from the same bytes: no write can leave a stale table behind.
+        """
+        self.chunk_crc[chunk] = zlib.crc32(blob)
+        if not self.chunk_block_crcs:
+            self.chunk_block_crcs = [""] * self.n_chunks
+        view, step = memoryview(blob), self.crc_block_bytes
+        crcs = np.fromiter((zlib.crc32(view[k : k + step]) for k in range(0, len(blob), step)),
+                           dtype="<u4")
+        self.chunk_block_crcs[chunk] = base64.b64encode(crcs.tobytes()).decode("ascii")
+
+    def block_crcs(self, chunk: int) -> Optional[np.ndarray]:
+        """The chunk's block CRCs, or ``None`` where it has no table."""
+        table = self.chunk_block_crcs[chunk] if self.chunk_block_crcs else ""
+        return np.frombuffer(base64.b64decode(table), dtype="<u4") if table else None
+
     def to_json(self) -> str:
         return json.dumps({"schema_version": MANIFEST_SCHEMA_VERSION, **self.__dict__})
 
@@ -145,6 +179,9 @@ class StripeManifest:
         if version < 4:
             # the write path did not exist: nothing can be dirty
             d.setdefault("chunk_dirty", [])
+        if version < 5:
+            # no block tables: reads verify whole chunks, as they were written
+            d.setdefault("chunk_block_crcs", [])
         return cls(**d)
 
 
@@ -281,7 +318,9 @@ class StripeStore:
             replication=int(replication),
             node_ids=[n.node_id for n in nodes],
             materialized=materialize,
+            crc_block_bytes=CRC_BLOCK_BYTES,
         )
+        man.chunk_crc = [0] * man.n_chunks
         resident = None
         if resident_chunks is not None:
             resident = {int(c) for c in resident_chunks}
@@ -296,7 +335,6 @@ class StripeStore:
                 # the remote store via the data plane's read-through path
                 man.chunk_nodes.append([])
                 man.chunk_filled.append(False)
-                man.chunk_crc.append(0)
                 continue
             replicas = [man.node_ids[(c + r) % nn] for r in range(replication)]
             man.chunk_nodes.append(replicas)
@@ -305,15 +343,7 @@ class StripeStore:
                 # remote_payload, not _default_payload: a re-admission after
                 # flushed overwrites must deliver what the remote store holds
                 blob = payload(c) if payload else self.remote_payload(man, c)
-                crc = zlib.crc32(blob)
-                man.chunk_crc.append(crc)
-                for node_id in replicas:
-                    path = self._chunk_path(dataset_id, node_id, c)
-                    os.makedirs(os.path.dirname(path), exist_ok=True)
-                    with open(path, "wb") as fh:
-                        fh.write(blob)
-            else:
-                man.chunk_crc.append(0)
+                self._land_chunk(man, c, blob, replicas)
             for node_id in replicas:
                 self.node_usage[node_id] += man.chunk_bytes
                 if not prefill:
@@ -348,6 +378,25 @@ class StripeStore:
             raise StripeError("materialized store needs a root directory")
         return os.path.join(self.root, f"node{node_id}", dataset_id, f"chunk_{chunk:06d}")
 
+    def _write_chunk(self, dataset_id: str, node_id: int, chunk: int, blob: bytes) -> None:
+        path = self._chunk_path(dataset_id, node_id, chunk)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "wb") as fh:
+            fh.write(blob)
+
+    def _land_chunk(
+        self, man: StripeManifest, chunk: int, blob: bytes, node_ids: Sequence[int]
+    ) -> None:
+        """Write new content of a chunk to ``node_ids``, with its CRCs.
+
+        Every write of new bytes goes through here; copies of a chunk's
+        verified bytes (repair, moves, heals) keep its CRCs and use
+        :meth:`_write_chunk` alone.
+        """
+        man.seal_chunk(chunk, blob)
+        for node_id in node_ids:
+            self._write_chunk(man.dataset_id, node_id, chunk, blob)
+
     # ------------------------------------------------------------- fill plane
     def put_chunk(
         self, dataset_id: str, chunk: int, payload: Optional[Callable[[int], bytes]] = None
@@ -372,12 +421,7 @@ class StripeStore:
             return False
         if man.materialized:
             blob = payload(chunk) if payload else self.remote_payload(man, chunk)
-            man.chunk_crc[chunk] = zlib.crc32(blob)
-            for node_id in man.chunk_nodes[chunk]:
-                path = self._chunk_path(dataset_id, node_id, chunk)
-                os.makedirs(os.path.dirname(path), exist_ok=True)
-                with open(path, "wb") as fh:
-                    fh.write(blob)
+            self._land_chunk(man, chunk, blob, man.chunk_nodes[chunk])
         man.chunk_filled[chunk] = True
         for node_id in man.chunk_nodes[chunk]:
             self._pending_fill[node_id] -= man.chunk_bytes
@@ -666,13 +710,7 @@ class StripeStore:
             if not replicas:
                 continue                         # wholly lost mid-fsync: keep buffering
             if man.materialized and p.data is not None:
-                blob = bytes(p.data)
-                man.chunk_crc[key[1]] = zlib.crc32(blob)
-                for node_id in replicas:
-                    path = self._chunk_path(dataset_id, node_id, key[1])
-                    os.makedirs(os.path.dirname(path), exist_ok=True)
-                    with open(path, "wb") as fh:
-                        fh.write(blob)
+                self._land_chunk(man, key[1], bytes(p.data), replicas)
             if not man.chunk_dirty:
                 man.chunk_dirty = [False] * man.n_chunks
             if not man.chunk_dirty[key[1]]:
@@ -863,19 +901,10 @@ class StripeStore:
             if man.chunk_dirty:
                 man.chunk_dirty[chunk] = False
             if man.materialized:
-                blob = self.remote_payload(man, chunk)
-                man.chunk_crc[chunk] = zlib.crc32(blob)
-                path = self._chunk_path(dataset_id, dst, chunk)
-                os.makedirs(os.path.dirname(path), exist_ok=True)
-                with open(path, "wb") as fh:
-                    fh.write(blob)
+                self._land_chunk(man, chunk, self.remote_payload(man, chunk), [dst])
             return True
         if man.materialized and man.is_filled(chunk):
-            blob = self._read_chunk(man, src, chunk)
-            path = self._chunk_path(dataset_id, dst, chunk)
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            with open(path, "wb") as fh:
-                fh.write(blob)
+            self._write_chunk(dataset_id, dst, chunk, self._read_chunk(man, src, chunk))
         if kind == "move":
             replicas[replicas.index(src)] = dst
             self.node_usage[src] -= cb
@@ -1053,6 +1082,13 @@ class StripeStore:
     def read_item(self, dataset_id: str, item: int, reader: Node) -> bytes:
         """Real-bytes read (materialized mode) with CRC verification.
 
+        Where the chunk has a block table, the read takes only the blocks
+        that cover the item from the chosen replica and checks each against
+        its CRC (``stripe.range_reads`` counts these reads); else it reads
+        and checks the whole chunk.  A failed check, a short read or a
+        missing file falls back to :meth:`read_chunk_verified`, which serves
+        the whole chunk from a healthy replica and heals the bad one.
+
         Spans ``stripe.locate``, ``stripe.io`` and ``stripe.verify`` and the
         ``stripe.*`` counters (:mod:`repro.core.hostspans`) time and count it.
         """
@@ -1080,8 +1116,14 @@ class StripeStore:
             return bytes(pending.data[off : off + man.item_bytes])
         with hostspans.span("stripe.locate"):
             src = self.locate(dataset_id, item, reader)
+        crcs = man.block_crcs(chunk)
         try:
-            blob = self._read_chunk(man, src.node_id, chunk)
+            if crcs is None:
+                blob = self._read_chunk(man, src.node_id, chunk)
+            else:
+                base, blob = self._read_blocks(man, src.node_id, chunk, crcs, off)
+                off -= base
+                hostspans.count("stripe.range_reads")
         except (ChunkCorruption, FileNotFoundError):
             # the chosen replica is corrupt or gone: fall back through the
             # verified path, which serves from a healthy copy AND rewrites
@@ -1093,6 +1135,34 @@ class StripeStore:
             )
         hostspans.count("stripe.bytes_delivered", man.item_bytes)
         return blob[off : off + man.item_bytes]
+
+    def _read_blocks(
+        self, man: StripeManifest, node_id: int, chunk: int, crcs: np.ndarray, off: int
+    ) -> tuple[int, bytes]:
+        """Read and check the blocks of a replica that cover one item at ``off``.
+
+        Returns the byte offset in the chunk at which the bytes read start,
+        and those bytes.  A block cut short or missing fails its CRC.
+        """
+        size = man.crc_block_bytes
+        first = off // size
+        stop = max(first, min(-(-(off + man.item_bytes) // size), len(crcs)))
+        path = self._chunk_path(man.dataset_id, node_id, chunk)
+        with hostspans.span("stripe.io"):
+            fd = os.open(path, os.O_RDONLY)
+            try:
+                data = os.pread(fd, (stop - first) * size, first * size)
+            finally:
+                os.close(fd)
+        hostspans.count("stripe.bytes_read", len(data))
+        with hostspans.span("stripe.verify"):
+            view = memoryview(data)
+            for k in range(first, stop):
+                block = view[(k - first) * size : (k - first + 1) * size]
+                if zlib.crc32(block) != crcs[k]:
+                    raise ChunkCorruption(
+                        f"{man.dataset_id} chunk {chunk} block {k} on node {node_id}")
+        return first * size, data
 
     def _read_chunk(self, man: StripeManifest, node_id: int, chunk: int) -> bytes:
         path = self._chunk_path(man.dataset_id, node_id, chunk)
@@ -1150,10 +1220,7 @@ class StripeStore:
                 failed.append(node_id)
                 continue
             for bad in failed:          # heal the replicas the fallback skipped
-                path = self._chunk_path(dataset_id, bad, chunk)
-                os.makedirs(os.path.dirname(path), exist_ok=True)
-                with open(path, "wb") as fh:
-                    fh.write(blob)
+                self._write_chunk(dataset_id, bad, chunk, blob)
                 self.corruption_repairs += 1
             return blob
         raise ChunkCorruption(
@@ -1213,10 +1280,7 @@ class StripeStore:
                 # only; the eventual put_chunk writes every replica
                 if man.materialized and man.is_filled(c):
                     blob = self.read_chunk_verified(dataset_id, c, self.topology.node(dst))
-                    path = self._chunk_path(dataset_id, dst, c)
-                    os.makedirs(os.path.dirname(path), exist_ok=True)
-                    with open(path, "wb") as fh:
-                        fh.write(blob)
+                    self._write_chunk(dataset_id, dst, c, blob)
                 replicas.append(dst)
                 self.node_usage[dst] += man.chunk_bytes
                 if not man.is_filled(c):
@@ -1246,11 +1310,7 @@ class StripeStore:
             dst = min(candidates, key=lambda nid: self.node_usage[nid])
             # unfilled chunks are a pure metadata retarget (no bytes on disk)
             if man.materialized and man.is_filled(c):
-                blob = self._read_chunk(man, node_id, c)
-                path = self._chunk_path(dataset_id, dst, c)
-                os.makedirs(os.path.dirname(path), exist_ok=True)
-                with open(path, "wb") as fh:
-                    fh.write(blob)
+                self._write_chunk(dataset_id, dst, c, self._read_chunk(man, node_id, c))
                 old = self._chunk_path(dataset_id, node_id, c)
                 if os.path.exists(old):
                     os.remove(old)

@@ -161,6 +161,57 @@ def test_corrupt_replica_is_one_fallback_and_its_bytes_are_read(tmp_path):
                           "stripe.bytes_delivered": 100}
 
 
+@pytest.fixture()
+def small_blocks(monkeypatch):
+    """New manifests record 256-byte CRC blocks: a 400-byte chunk spans two."""
+    from repro.core import stripestore
+
+    monkeypatch.setattr(stripestore, "CRC_BLOCK_BYTES", 256)
+
+
+@pytest.mark.parametrize("slot, read", [(0, 256), (2, 400), (3, 144)],
+                         ids=["first-block", "both-blocks", "short-last-block"])
+def test_range_read_counters_are_exact_on_a_multi_block_store(small_blocks, tmp_path,
+                                                              slot, read):
+    store, reader = _store(tmp_path, "rr")
+    with hostspans.batch():
+        store.read_item("rr", 4 + slot, reader)
+    (r,) = hostspans.last(1)
+    assert r.counters == {"stripe.bytes_read": read, "stripe.range_reads": 1,
+                          "stripe.bytes_delivered": 100}
+
+
+def test_loader_range_reads_are_counted_per_item(small_blocks, tmp_path):
+    """64-byte token items, 16 to a 1,024-byte chunk: each item read reads its
+    one 256-byte block, so the amplification is 4 where whole chunks gave 16."""
+    _, topo, store, cache, _ = build_cluster()
+    store.root = str(tmp_path)
+    spec = TokenDatasetSpec("rb", n_sequences=64, seq_len=SEQ, vocab=100)
+    materialize_token_dataset(store, cache, spec, topo.nodes[:4], items_per_chunk=16)
+    recs = _read_batches(store, spec, topo.nodes[0], n=3)
+    for r in recs:
+        assert r.counters == {"stripe.bytes_read": 4 * 256, "stripe.range_reads": 4,
+                              "stripe.bytes_delivered": 4 * ITEM_B}
+    assert hostspans.read_amplification(recs) == 4
+
+
+def test_corrupt_block_falls_back_to_a_whole_chunk_read(small_blocks, tmp_path):
+    store, reader = _store(tmp_path, "cb", replication=2)
+    bad = store.locate("cb", 6, reader).node_id
+    path = Path(store._chunk_path("cb", bad, 1))
+    blob = bytearray(path.read_bytes())
+    blob[300] ^= 0xFF
+    path.write_bytes(bytes(blob))
+    with hostspans.batch():
+        store.read_item("cb", 6, reader)                 # bytes 200-300: both blocks
+        store.read_item("cb", 4, reader)                 # bytes 0-100: the healed copy
+    (r,) = hostspans.last(1)
+    # the failed range read (400), the healthy replica's whole chunk (400),
+    # then one block of the healed replica (256)
+    assert r.counters == {"stripe.bytes_read": 400 + 400 + 256, "stripe.fallbacks": 1,
+                          "stripe.range_reads": 1, "stripe.bytes_delivered": 200}
+
+
 def test_remote_read_through_counts_delivery_and_opens_no_io(tmp_path):
     store, reader = _store(tmp_path, "rt", fraction=0.5)
     assert not store.manifests["rt"].chunk_nodes[5]

@@ -27,6 +27,7 @@ from repro.core import (
     CacheState,
     DatasetSpec,
     FillTracker,
+    MANIFEST_SCHEMA_VERSION,
     PlacementEngine,
     RebalanceError,
     Rebalancer,
@@ -87,7 +88,7 @@ def test_manifest_v3_roundtrip_and_legacy():
     assert not any(StripeManifest.from_json(json.dumps(blob)).chunk_dirty)
 
     # future versions are refused, never guessed
-    blob["schema_version"] = 5
+    blob["schema_version"] = MANIFEST_SCHEMA_VERSION + 1
     with pytest.raises(StripeError, match="newer"):
         StripeManifest.from_json(json.dumps(blob))
 

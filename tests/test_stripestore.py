@@ -1,8 +1,10 @@
 """Stripe store: round-trips, replication, corruption repair, node loss."""
 
+import base64
 import dataclasses
 import json
 import os
+import zlib
 
 import numpy as np
 import pytest
@@ -16,6 +18,7 @@ from repro.core import (
     Topology,
     TopologyConfig,
 )
+from repro.core import hostspans, stripestore
 from repro.core.stripestore import ChunkCorruption
 
 
@@ -357,3 +360,197 @@ def test_drain_straggler_node(topo, tmp_path):
     assert all(1 not in reps for reps in man.chunk_nodes)
     for item in range(32):
         assert len(store.read_item("ds", item, topo.nodes[0])) == 64
+
+
+# ------------------------------------------------- item-range reads (schema v5)
+BLOCK, ITEM, IPC = 256, 100, 10          # 1000-byte chunks: blocks 256, 256, 256, 232
+
+
+@pytest.fixture()
+def small_blocks(monkeypatch):
+    """New manifests record 256-byte CRC blocks, so a chunk spans four."""
+    monkeypatch.setattr(stripestore, "CRC_BLOCK_BYTES", BLOCK)
+
+
+def _payload(c: int) -> bytes:
+    return np.random.default_rng(c).bytes(ITEM * IPC)
+
+
+def _blocked_store(topo, tmp_path, *, replication=2, **kw):
+    store = _mk_store(topo, tmp_path)
+    man = store.create("ds", n_items=4 * IPC, item_bytes=ITEM, nodes=topo.nodes[:4],
+                       items_per_chunk=IPC, replication=replication, materialize=True,
+                       payload=_payload, **kw)
+    return store, man
+
+
+def _item(c: int, slot: int) -> bytes:
+    return _payload(c)[slot * ITEM : (slot + 1) * ITEM]
+
+
+def _flip(store, node_id, chunk, at):
+    path = store._chunk_path("ds", node_id, chunk)
+    with open(path, "r+b") as fh:
+        fh.seek(at)
+        b = fh.read(1)
+        fh.seek(at)
+        fh.write(bytes([b[0] ^ 0xFF]))
+
+
+def _read_counted(store, item, reader):
+    with hostspans.batch():
+        raw = store.read_item("ds", item, reader)
+    return raw, hostspans.last(1)[0].counters
+
+
+def _tables_match_disk(store, man):
+    """Every replica file's whole and block CRCs are the manifest's."""
+    for c, reps in enumerate(man.chunk_nodes):
+        if not reps or not man.is_filled(c):
+            continue
+        for nid in reps:
+            with open(store._chunk_path(man.dataset_id, nid, c), "rb") as fh:
+                blob = fh.read()
+            assert zlib.crc32(blob) == man.chunk_crc[c]
+            want = [zlib.crc32(blob[k : k + man.crc_block_bytes])
+                    for k in range(0, len(blob), man.crc_block_bytes)]
+            assert man.block_crcs(c).tolist() == want
+
+
+@pytest.mark.parametrize("slot", [0, 2, 5, 8, 9],
+                         ids=["chunk-start", "across-256", "across-512", "short-last-block",
+                              "chunk-end"])
+def test_range_read_round_trips(small_blocks, topo, tmp_path, slot):
+    store, man = _blocked_store(topo, tmp_path)
+    assert man.crc_block_bytes == BLOCK and len(man.block_crcs(0)) == 4
+    for c in range(man.n_chunks):
+        raw, counters = _read_counted(store, c * IPC + slot, topo.nodes[0])
+        assert raw == _item(c, slot)
+        assert counters["stripe.range_reads"] == 1 and "stripe.fallbacks" not in counters
+    assert store.corruption_repairs == 0
+
+
+def test_range_read_at_the_default_block_size(topo, tmp_path):
+    """24,000-byte items in 192,000-byte chunks: three 64 KiB blocks, the last
+    short; item 2 straddles the first boundary and reads two blocks."""
+    store = _mk_store(topo, tmp_path)
+    payload = np.random.default_rng(7).bytes(8 * 24_000)
+    man = store.create("big", n_items=8, item_bytes=24_000, nodes=topo.nodes[:1],
+                       items_per_chunk=8, materialize=True, payload=lambda c: payload)
+    assert man.crc_block_bytes == stripestore.CRC_BLOCK_BYTES == 64 * 1024
+    assert len(man.block_crcs(0)) == 3
+    for item in range(8):
+        with hostspans.batch():
+            raw = store.read_item("big", item, topo.nodes[0])
+        (rec,) = hostspans.last(1)
+        assert raw == payload[item * 24_000 : (item + 1) * 24_000]
+        assert rec.counters["stripe.range_reads"] == 1
+    assert rec.counters["stripe.bytes_read"] == 192_000 - 2 * 65_536     # the short block
+
+
+def test_flipped_byte_in_the_items_block_falls_back_and_heals(small_blocks, topo, tmp_path):
+    store, man = _blocked_store(topo, tmp_path)
+    reader = topo.nodes[0]
+    victim = store.locate("ds", 15, reader).node_id          # chunk 1, bytes 500-600
+    _flip(store, victim, 1, 520)
+    raw, counters = _read_counted(store, 15, reader)
+    assert raw == _item(1, 5)
+    assert counters["stripe.fallbacks"] == 1 and "stripe.range_reads" not in counters
+    assert store.corruption_repairs == 1
+    assert store._read_chunk(man, victim, 1) == _payload(1)  # healed in place
+
+
+def test_flipped_byte_in_another_block_is_left_to_the_whole_chunk_check(
+        small_blocks, topo, tmp_path):
+    """An item read checks what it reads: a flip in block 3 does not touch an
+    item of block 0, and the whole-chunk verified read still finds and heals it."""
+    store, man = _blocked_store(topo, tmp_path)
+    reader = topo.nodes[0]
+    victim = store.locate("ds", 10, reader).node_id
+    _flip(store, victim, 1, 900)
+    raw, counters = _read_counted(store, 10, reader)
+    assert raw == _item(1, 0)
+    assert counters["stripe.range_reads"] == 1 and "stripe.fallbacks" not in counters
+    assert store.corruption_repairs == 0
+    with pytest.raises(ChunkCorruption):
+        store._read_chunk(man, victim, 1)
+    assert store.read_chunk_verified("ds", 1, topo.nodes[victim]) == _payload(1)
+    assert store.corruption_repairs == 1
+    assert store._read_chunk(man, victim, 1) == _payload(1)
+
+
+@pytest.mark.parametrize("slot", [5, 9], ids=["block-cut-short", "block-gone"])
+def test_truncated_replica_falls_back_and_heals(small_blocks, topo, tmp_path, slot):
+    store, man = _blocked_store(topo, tmp_path)
+    reader = topo.nodes[0]
+    victim = store.locate("ds", IPC + slot, reader).node_id
+    os.truncate(store._chunk_path("ds", victim, 1), 600)     # mid block 2
+    raw, counters = _read_counted(store, IPC + slot, reader)
+    assert raw == _item(1, slot)
+    assert counters["stripe.fallbacks"] == 1
+    assert store.corruption_repairs == 1
+    assert store._read_chunk(man, victim, 1) == _payload(1)
+
+
+def test_bad_block_without_a_second_replica_raises(small_blocks, topo, tmp_path):
+    store, man = _blocked_store(topo, tmp_path, replication=1)
+    _flip(store, man.chunk_nodes[2][0], 2, 300)
+    with pytest.raises(ChunkCorruption):
+        store.read_item("ds", 2 * IPC + 3, topo.nodes[0])
+    assert store.read_item("ds", 2 * IPC + 0, topo.nodes[0]) == _item(2, 0)
+
+
+def test_v4_manifest_loads_and_reads_whole_chunks(small_blocks, topo, tmp_path):
+    store, man = _blocked_store(topo, tmp_path)
+    blob = json.loads(man.to_json())
+    for key in ("crc_block_bytes", "chunk_block_crcs"):
+        blob.pop(key)
+    blob["schema_version"] = 4
+    old = StripeManifest.from_json(json.dumps(blob))
+    assert old.chunk_block_crcs == [] and old.block_crcs(0) is None
+    store.manifests["ds"] = old
+    raw, counters = _read_counted(store, 12, topo.nodes[0])
+    assert raw == _item(1, 2)
+    assert counters == {"stripe.bytes_read": ITEM * IPC, "stripe.bytes_delivered": ITEM}
+
+
+def test_v5_manifest_round_trips_its_block_table(small_blocks, topo, tmp_path):
+    store, man = _blocked_store(topo, tmp_path)
+    d = json.loads(man.to_json())
+    assert d["schema_version"] == 5 and d["crc_block_bytes"] == BLOCK
+    table = np.frombuffer(base64.b64decode(d["chunk_block_crcs"][3]), dtype="<u4")
+    want = [zlib.crc32(_payload(3)[k : k + BLOCK]) for k in range(0, ITEM * IPC, BLOCK)]
+    assert table.tolist() == want
+    again = StripeManifest.from_json(man.to_json())
+    assert dataclasses.asdict(again) == dataclasses.asdict(man)
+    _tables_match_disk(store, again)
+
+
+def test_range_reads_verify_after_every_write_of_new_bytes(small_blocks, topo, tmp_path):
+    """put_chunk, commit_writes and a refetch's commit_transfer each write new
+    bytes with fresh tables: reads that follow find no false corruption."""
+    store, man = _blocked_store(topo, tmp_path, replication=1, prefill=False)
+    for c in range(man.n_chunks):
+        store.put_chunk("ds", c, payload=_payload)
+    _tables_match_disk(store, man)
+    reader = topo.nodes[0]
+    writer = man.chunk_nodes[1][0]
+    store.write_pending("ds", 1, 250, b"w" * 300, writer)   # blocks 0-2
+    store.commit_writes("ds", [1], writer)
+    _tables_match_disk(store, man)
+    lost = man.chunk_nodes[2][0]
+    store.fail_node(lost)                                    # chunk 2 has no replica left
+    dst = next(n.node_id for n in topo.nodes[:4] if n.node_id != lost)
+    assert store.begin_transfer("ds", 2, None, dst, kind="refetch")
+    assert store.commit_transfer("ds", 2)
+    _tables_match_disk(store, man)
+    written = bytearray(_payload(1))
+    written[250:550] = b"w" * 300
+    refetched = store.remote_payload(man, 2)
+    assert refetched != _payload(2)
+    for slot in range(IPC):
+        for c, want in ((0, _payload(0)), (1, bytes(written)), (2, refetched)):
+            raw, counters = _read_counted(store, c * IPC + slot, reader)
+            assert raw == want[slot * ITEM : (slot + 1) * ITEM]
+            assert counters["stripe.range_reads"] == 1, (c, slot)
+    assert store.corruption_repairs == 0
