@@ -101,8 +101,6 @@ class ModelConfig:
                                        # materialised broadcast pred buffers)
     moe_token_shard: bool = False      # constrain MoE dispatch buffers to
                                        # stay data-sharded (perf feature)
-    ssm_factored: bool = False         # factored selective scan (no global
-                                       # (B,S,h,chd,N) materialisation)
 
     @property
     def resolved_head_dim(self) -> int:

@@ -1,8 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+"""Pure-jnp oracles of the model primitives (the allclose ground truth).
 
+The oracle for ``models/layers.py`` (attention, decode attention, RMSNorm,
+SwiGLU), ``models/xlstm.py`` (the chunked mLSTM) and ``models/hymba.py``.
 Deliberately naive: full-materialisation softmax attention, direct per-step
-recurrences, unfused norms.  Tests sweep shapes/dtypes and assert each kernel
-(interpret mode) against these.
+recurrences, unfused norms.  Tests sweep shapes/dtypes and assert each
+primitive against these.
 """
 
 from __future__ import annotations

@@ -8,8 +8,7 @@ The mLSTM recurrence (xLSTM paper, exp-gating stabilized)
 admits a chunkwise-parallel form: within a chunk of length L all pair weights
 are D_{ts} = exp(b_t - b_s + i_s - m_t) (b = cumulative log-f, m = running
 max stabilizer), computed as an (L, L) masked matrix; across chunks a small
-scan carries (C, n, m).  This is the TPU-friendly layout (the Pallas kernel
-in ``repro.kernels.mlstm_scan`` tiles exactly this form) — the same math the
+scan carries (C, n, m).  This is the TPU-friendly layout — the same math the
 official CUDA kernels implement, reorganised for MXU-sized matmuls.
 
 sLSTM blocks (1 per ``slstm_every``) are genuinely sequential (recurrent

@@ -1,19 +1,20 @@
-"""Analytic cost estimates for the pallas kernels (``pl.CostEstimate`` math).
+"""Analytic cost of the attention, mLSTM and SSD work in a training step.
 
-Each function mirrors the grid/BlockSpec arithmetic of the colocated kernel
-(``flash_attention.py``, ``mlstm_scan.py``, ``ssd_scan.py``) and returns the
-same three quantities a ``pl.CostEstimate`` declares to the compiler:
-``flops``, ``bytes_accessed`` and ``transcendentals``.  The roofline table
-generator (:mod:`repro.roofline.table`) sums these per layer to price the
-attention/scan work that the dense ``6*N*D`` matmul model does not cover.
+The dense ``6*N*D`` matmul model of :mod:`repro.roofline.analysis` prices
+the parameter matmuls only.  These functions price the sequence-mixing work
+it misses: attention over the visited context, the chunked mLSTM scan and
+the chunked Mamba-2 SSD scan.  Each returns the three quantities a
+``pl.CostEstimate`` declares to the compiler (``flops``, ``bytes_accessed``
+and ``transcendentals``) for a flash-style tiling of that work.  The
+roofline table generator (:mod:`repro.roofline.table`) sums them per layer.
 
 Conventions (shared with :mod:`repro.roofline.analysis`):
 
 * FLOPs count MXU work only (2 per multiply-accumulate); vector-unit
   elementwise work rides along free.
-* ``bytes_accessed`` is HBM traffic of the tiled kernel: operand tiles are
-  charged once per grid visit (flash attention re-streams K/V once per query
-  block — that re-read is the kernel's real memory cost), outputs once.
+* ``bytes_accessed`` is HBM traffic of the tiled computation: operand tiles
+  are charged once per grid visit (flash attention re-streams K/V once per
+  query block — that re-read is its real memory cost), outputs once.
 * Masked-out work is *not* charged: causal/windowed attention prices the
   average visited context per query, matching the ``causal_pairs`` block
   enumeration rather than the dense rectangle.
@@ -79,7 +80,7 @@ def flash_attention_cost(
     block_k: int = 128,
     dtype_bytes: int = 2,
 ) -> KernelCost:
-    """Forward cost of one ``flash_attention`` call.
+    """Forward cost of one flash-tiled attention call.
 
     FLOPs: ``QK^T`` and ``PV`` are each ``2*ctx*head_dim`` per query per
     head; transcendentals: one ``exp`` per visited score.  Bytes: Q and O
@@ -108,7 +109,7 @@ def mlstm_scan_cost(
     chunk: int = 128,
     dtype_bytes: int = 2,
 ) -> KernelCost:
-    """Forward cost of one chunked ``mlstm_scan`` call.
+    """Forward cost of one chunked mLSTM scan (``models.xlstm.mlstm_chunked``).
 
     Per token per head: intra-chunk pair weights cost ``2*L*(d_qk + d_v)``
     (QK^T over the chunk + the (L,L)@V read-out), the cross-chunk matrix
@@ -133,7 +134,7 @@ def ssd_scan_cost(
     chunk: int = 128,
     dtype_bytes: int = 2,
 ) -> KernelCost:
-    """Forward cost of one chunked ``ssd_scan`` (Mamba-2 SSD) call.
+    """Forward cost of one chunked ``models.hymba.ssd_scan`` (Mamba-2 SSD) call.
 
     Per token per head: intra-chunk decay-weighted pair read-out
     ``2*L*head_channels``, plus the carried (chd, N) state — outer-product
@@ -150,7 +151,7 @@ def ssd_scan_cost(
 def swiglu_cost(
     tokens: int, d_model: int, d_ff: int, *, dtype_bytes: int = 2
 ) -> KernelCost:
-    """Forward cost of one ``swiglu_mlp`` call (three matmuls + gate)."""
+    """Forward cost of one ``models.layers.swiglu`` call (three matmuls + gate)."""
     flops = 6.0 * tokens * d_model * d_ff
     io_bytes = (2.0 * tokens * d_model + 3.0 * d_model * d_ff) * dtype_bytes
     return KernelCost(flops, io_bytes, float(tokens * d_ff))

@@ -9,12 +9,12 @@ with the three terms assembled from the repo's own pieces:
 
 * **compute** — ``6*N*D`` matmul FLOPs (:func:`repro.roofline.analysis
   .model_flops`, N from the real model layouts in ``models/registry.py``)
-  plus the attention/scan kernel FLOPs from the pallas cost estimates
-  (:mod:`repro.kernels.cost`), over ``chips * PEAK_FLOPS``;
+  plus the attention/scan FLOPs from the analytic cost model
+  (:mod:`repro.roofline.cost`), over ``chips * PEAK_FLOPS``;
 * **memory** — per-chip HBM traffic: weight reads (fwd+bwd), AdamW
   optimizer-state sweep, activation reads/writes
-  (``ACT_PASSES * layers * tokens/dp * d_model`` bytes) and the kernels'
-  tiled ``bytes_accessed``, over ``HBM_BW``;
+  (``ACT_PASSES * layers * tokens/dp * d_model`` bytes) and the attention/scan
+  work's tiled ``bytes_accessed``, over ``HBM_BW``;
 * **collective** — ring gradient all-reduce over the data axis plus
   tensor-parallel activation all-reduces over the model axis (raw per-chip
   byte sum, the convention of :mod:`repro.roofline.analysis`), over
@@ -44,7 +44,7 @@ from typing import Optional
 
 from ..configs import ARCHS, SHAPES, shape_applicable
 from ..configs.base import ModelConfig, ShapeConfig
-from ..kernels.cost import (
+from .cost import (
     KernelCost,
     ZERO_COST,
     flash_attention_cost,

@@ -4,8 +4,8 @@ The baseline decode path shards the KV cache's sequence dimension on the
 ``model`` axis and lets GSPMD partition the softmax; this module does it
 *explicitly* with ``shard_map``: every chip computes a partial online-softmax
 (m, l, acc) over its local KV shard, and partials merge with one small
-all-reduce-style combine — the cross-chip mirror of the Pallas
-``decode_attention`` kernel's block algebra (same math, chip-sized blocks).
+all-reduce-style combine — the block algebra of flash attention's online
+softmax, with one chip's KV shard as the block.
 
 Why it matters at scale: GQA head counts in the pool (5, 10, 20, 25) do not
 divide a 16-way TP axis, so head-sharding cannot cover decode; sequence

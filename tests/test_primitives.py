@@ -1,17 +1,12 @@
-"""Pallas kernel sweeps: shapes x dtypes vs pure-jnp oracles (interpret mode)."""
+"""Model primitives against pure-jnp oracles: shapes x dtypes sweeps."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.mlstm_scan import mlstm_scan
-from repro.kernels.rmsnorm import rmsnorm
-from repro.kernels.swiglu import swiglu_mlp
-from repro.models.layers import blockwise_attention
+from repro.models import ref
+from repro.models.layers import blockwise_attention, decode_attention, rms_norm, swiglu
 from repro.models.xlstm import mlstm_chunked
 
 RNG = np.random.default_rng(42)
@@ -27,38 +22,26 @@ def _tol(dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "B,Hq,Hkv,Sq,Skv,hd,causal,window,bq,bk",
+    "B,Hq,Hkv,Sq,Skv,hd,causal,window,bq,bk,pairs",
     [
-        (1, 2, 2, 128, 128, 32, True, 0, 64, 64),
-        (2, 4, 2, 256, 256, 64, True, 0, 128, 64),
-        (1, 8, 2, 192, 192, 32, True, 64, 64, 64),      # SWA + ragged blocks
-        (2, 2, 2, 128, 256, 64, False, 0, 64, 128),     # cross attention
-        (1, 4, 4, 100, 100, 16, True, 0, 64, 64),       # unaligned seq
+        (2, 4, 2, 256, 256, 32, True, 0, 64, 64, True),
+        (2, 4, 2, 256, 256, 32, True, 128, 64, 64, True),
+        (2, 4, 2, 256, 256, 32, False, 0, 64, 64, False),
+        (1, 2, 2, 128, 128, 32, True, 0, 64, 64, False),
+        (2, 4, 2, 256, 256, 64, True, 0, 128, 64, False),
+        (1, 8, 2, 192, 192, 32, True, 64, 64, 64, False),     # SWA + ragged blocks
+        (2, 2, 2, 128, 256, 64, False, 0, 64, 128, False),    # cross attention
+        (1, 4, 4, 100, 100, 16, True, 0, 64, 64, False),      # unaligned seq
     ],
 )
-def test_flash_attention_sweep(dtype, B, Hq, Hkv, Sq, Skv, hd, causal, window, bq, bk):
+def test_xla_blockwise_matches_oracle(dtype, B, Hq, Hkv, Sq, Skv, hd, causal, window,
+                                      bq, bk, pairs):
+    """The model-side XLA attention (both enumerations) equals the oracle."""
     q = _rand((B, Hq, Sq, hd), dtype)
     k = _rand((B, Hkv, Skv, hd), dtype)
     v = _rand((B, Hkv, Skv, hd), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=bq, block_k=bk, interpret=True)
-    want = ref.attention_ref(q, k, v, causal=causal, window=window)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(want, np.float32), **_tol(dtype)
-    )
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize(
-    "causal,window,pairs", [(True, 0, True), (True, 128, True), (False, 0, False)]
-)
-def test_xla_blockwise_matches_oracle(dtype, causal, window, pairs):
-    """The model-side XLA attention (both enumerations) equals the oracle."""
-    q = _rand((2, 4, 256, 32), dtype)
-    k = _rand((2, 2, 256, 32), dtype)
-    v = _rand((2, 2, 256, 32), dtype)
     out = blockwise_attention(q, k, v, causal=causal, window=window,
-                              q_block=64, kv_block=64, pairs=pairs)
+                              q_block=bq, kv_block=bk, pairs=pairs)
     want = ref.attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -83,7 +66,7 @@ def test_decode_attention_sweep(dtype, valid, window):
     q = _rand((B, Hq, 1, hd), dtype)
     kc = _rand((B, Hkv, S, hd), dtype)
     vc = _rand((B, Hkv, S, hd), dtype)
-    out = decode_attention(q, kc, vc, valid, window=window, block_k=128, interpret=True)
+    out = decode_attention(q, kc, vc, valid, window=window)
     want = ref.attention_ref(q, kc, vc, causal=False, valid_len=valid, window=0)
     if window:
         # oracle with window mask anchored at valid-1
@@ -102,11 +85,11 @@ def test_decode_attention_sweep(dtype, valid, window):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("rows,D,block", [(64, 128, 32), (100, 96, 64), (256, 512, 256)])
-def test_rmsnorm_sweep(dtype, rows, D, block):
+@pytest.mark.parametrize("rows,D", [(64, 128), (100, 96), (256, 512)])
+def test_rmsnorm_sweep(dtype, rows, D):
     x = _rand((rows, D), dtype)
     g = _rand((D,), dtype)
-    out = rmsnorm(x, g, block_rows=block, interpret=True)
+    out = rms_norm(x, g)
     want = ref.rmsnorm_ref(x, g)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -114,13 +97,13 @@ def test_rmsnorm_sweep(dtype, rows, D, block):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("N,D,F,bm,bf", [(64, 64, 128, 32, 64), (100, 96, 224, 64, 64)])
-def test_swiglu_sweep(dtype, N, D, F, bm, bf):
+@pytest.mark.parametrize("N,D,F", [(64, 64, 128), (100, 96, 224)])
+def test_swiglu_sweep(dtype, N, D, F):
     x = _rand((N, D), dtype) * 0.5
     wg = _rand((D, F), dtype) * 0.1
     wu = _rand((D, F), dtype) * 0.1
     wd = _rand((F, D), dtype) * 0.1
-    out = swiglu_mlp(x, wg, wu, wd, block_m=bm, block_f=bf, interpret=True)
+    out = swiglu(x, wg, wu, wd)
     want = ref.swiglu_ref(x, wg, wu, wd)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32),
                                rtol=5e-2 if dtype == jnp.bfloat16 else 1e-4,
@@ -130,25 +113,13 @@ def test_swiglu_sweep(dtype, N, D, F, bm, bf):
 @pytest.mark.parametrize("chunk", [32, 64, 128])
 @pytest.mark.parametrize("dqk,dv", [(16, 32), (32, 32)])
 def test_mlstm_kernel_sweep(chunk, dqk, dv):
+    """The model's chunked mLSTM scan equals the sequential recurrence."""
     B, H, S = 2, 2, 256
     q = _rand((B, H, S, dqk), jnp.float32)
     k = _rand((B, H, S, dqk), jnp.float32)
     v = _rand((B, H, S, dv), jnp.float32)
     i_raw = _rand((B, H, S), jnp.float32)
     log_f = jnp.asarray(np.log(RNG.uniform(0.7, 1.0, (B, H, S))), jnp.float32)
-    out = mlstm_scan(q, k, v, i_raw, log_f, chunk=chunk, interpret=True)
+    out = mlstm_chunked(q, k, v, i_raw, log_f, chunk=chunk)
     want = ref.mlstm_ref(q, k, v, i_raw, log_f)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-def test_mlstm_kernel_matches_xla_chunked():
-    """Kernel and the model's XLA chunked path agree exactly in algorithm."""
-    B, H, S, dqk, dv = 1, 2, 128, 16, 32
-    q = _rand((B, H, S, dqk), jnp.float32)
-    k = _rand((B, H, S, dqk), jnp.float32)
-    v = _rand((B, H, S, dv), jnp.float32)
-    i_raw = _rand((B, H, S), jnp.float32)
-    log_f = jnp.asarray(np.log(RNG.uniform(0.8, 1.0, (B, H, S))), jnp.float32)
-    a = mlstm_scan(q, k, v, i_raw, log_f, chunk=32, interpret=True)
-    b = mlstm_chunked(q, k, v, i_raw, log_f, chunk=32)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 
 from repro.core import SimClock, Resource
 from repro.core.loader import EpochPlan
-from repro.kernels import ref
+from repro.models import ref
 from repro.models.layers import band_pairs, blockwise_attention
 
 

@@ -13,7 +13,7 @@ import json
 import pytest
 
 from repro.configs import ARCHS, SHAPES
-from repro.kernels.cost import (
+from repro.roofline.cost import (
     ZERO_COST,
     KernelCost,
     avg_context,
@@ -136,7 +136,7 @@ def test_hlo_walk_known_trip_count_overrides_cond():
     assert hlo_walk.multipliers(comps)["body"] == 12
 
 
-# ------------------------------------------------------------- kernels/cost.py
+# ------------------------------------------------------------- roofline/cost.py
 
 def test_avg_context_causal_and_windowed():
     assert avg_context(64, 64) == pytest.approx((64 + 1) / 2)
