@@ -24,9 +24,11 @@ Four chips: the trainer's entry point again, with ``--mesh data=4,model=1``
 (ZeRO data parallelism: params replicated, AdamW's state sharded over the four
 chips), at the same global batch of 8 x 512 for 6 steps.  It fails unless
 attention was lowered to the Pallas kernel alone, every param and optimizer
-leaf spans the four chips and some optimizer leaves are sharded, and it prints
-each chip's peak memory.  Then the same run on one chip: each step's loss
-within a relative 2e-2 of the four chips'.
+leaf spans the four chips, some optimizer leaves are sharded and none on a
+layer scan axis (it prints ``zero_placement_tally``: AdamW's state leaves
+and bytes by where ZeRO put them), and it prints each chip's peak memory.
+Then the same run on one chip: each step's loss within a relative 2e-2 of
+the four chips'.
 
 The script refuses to run anywhere but a TPU.  It prints what it checked,
 the device's peak memory and the attention timing lines, never a step time;
@@ -59,7 +61,7 @@ from repro.models.layers import (  # noqa: E402
     attention, attention_path_tally, blockwise_attention, flash_causal_attention,
 )
 from repro.train import (  # noqa: E402
-    AdamWConfig, CheckpointManager, SamplerState, init_train_state,
+    AdamWConfig, CheckpointManager, SamplerState, init_train_state, zero_placement_tally,
 )
 
 ARCH = "qwen1.5-0.5b"
@@ -265,6 +267,11 @@ def four_chips(work: Path) -> None:
           f"{zero} of {len(jax.tree.leaves(four.opt))} optimizer leaves are ZeRO-sharded")
     check(spans == {4}, f"param/opt leaves span {sorted(spans)} devices, want 4")
     check(zero > 0, "no optimizer leaf is sharded over the data axis")
+    mesh = train.build_mesh({"data": 4, "model": 1})
+    tally = zero_placement_tally(build_model(model_config(), mesh=mesh, model_axis=1).layout(),
+                                 mesh, AdamWConfig())
+    print(f"AdamW state by ZeRO placement, (leaves, bytes): {tally}")
+    check(tally["scan_axis"][0] == 0, f"optimizer state sharded on a layer scan axis: {tally}")
     peaks = [peak_bytes(d) for d in devices[:4]]
     print(f"peak_bytes_in_use per device={peaks}")
     print(f"memory_stats[0]={devices[0].memory_stats()}")

@@ -3,7 +3,17 @@
 ``build_sharded_step`` returns the step jitted with explicit in/out
 shardings on ``mesh`` — the model layout's specs for params, ZeRO specs for
 the optimizer state, data-parallel specs for the batch — together with its
-abstract arguments (ShapeDtypeStructs, no allocation).  Lower and compile it
+abstract arguments (ShapeDtypeStructs, no allocation).
+
+ZeRO (``train/optimizer.py`` ``opt_state_specs``) shards a layer-stacked
+leaf's state on a dimension within the layer, not on the scan axis that
+``models/params.py`` ``stack`` prepends (unless no other dimension
+divides).  The backward layer scan yields one layer's gradient per
+iteration: with the state split by layer, the partitioner all-reduces that
+whole gradient on every chip inside the loop, exposed; split within the
+layer, the gradient leaves the loop as reduce-scatters that XLA overlaps
+with the weight-gradient matmuls, and the updated parameters are
+all-gathered after AdamW.  Lower and compile it
 from the abstract arguments, or call it on arrays placed with its shardings.
 
 Importing this module touches no device and writes no environment.

@@ -35,7 +35,11 @@ an executable cached before the scopes existed is loaded without them: clear
 the cache directory (or set ``jax_compilation_cache_include_metadata_in_key``)
 before profiling a step that was cached by an older build.  The first step's
 line also prints how many attention calls were lowered to each path
-(``pallas_flash``, ``xla_blockwise``; see ``models/layers.py`` ``attention``).
+(``pallas_flash``, ``xla_blockwise``; see ``models/layers.py`` ``attention``)
+and, under ``--mesh``, where ZeRO put AdamW's state: ``zero scan_axis=<leaves>/
+<bytes>B inner_axis=... replicated=...`` (``train/optimizer.py``
+``zero_placement_tally``; a layer-stacked leaf's state belongs off its scan
+axis).
 
 The smoke-sized model by default; ``--full-config`` for the architecture's
 published widths.  Usage:
@@ -75,6 +79,7 @@ from ..train import (
     init_train_state,
     make_train_step,
     run_with_restarts,
+    zero_placement_tally,
 )
 from .compile_cache import enable_compile_cache
 from .mesh import make_test_mesh
@@ -191,6 +196,7 @@ def main(argv=None) -> TrainResult:
 
         def put(toks, labels):
             return {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
+        zero_line = ""
     else:
         mesh = build_mesh(args.mesh)
         st = build_sharded_step(cfg, ShapeConfig("train", args.seq, args.batch, "train"), mesh,
@@ -198,6 +204,8 @@ def main(argv=None) -> TrainResult:
         step_fn = st.jitted
         init_opt = jax.jit(lambda p: init_opt_state(p, opt_cfg), out_shardings=st.opt_sharding)
         print(f"[mesh] {dict(mesh.shape)}, global batch {args.batch}")
+        zero_line = " zero " + " ".join(
+            f"{k}={n}/{b}B" for k, (n, b) in zero_placement_tally(st.layout, mesh, opt_cfg).items())
 
         def init_state(key):
             params = jax.device_put(PM.materialize(st.layout, key, cfg.dtype), st.param_sharding)
@@ -235,7 +243,7 @@ def main(argv=None) -> TrainResult:
                             f"gnorm={float(metrics['grad_norm']):.3f}")
                     if step == start:
                         line += " attention " + " ".join(
-                            f"{k}={n}" for k, n in attention_path_tally().items())
+                            f"{k}={n}" for k, n in attention_path_tally().items()) + zero_line
                     now = time.perf_counter()
                     print(line + _timings(fetched, step, now))
                     fetched = (step, now)

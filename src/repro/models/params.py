@@ -9,7 +9,10 @@ derive:
 * ``specs(layout)``             — the matching PartitionSpec tree for pjit.
 
 Stacked (scan-over-layers) blocks call :func:`stack` to prepend the layer
-axis to every leaf (sharding ``None`` on that axis).
+axis to every leaf (sharding ``None`` on that axis).  It is the one place
+that adds a scan axis, and it counts them in ``ParamInfo.stacked``: a
+layer scan slices those leading axes, which the ZeRO rule
+(``train/optimizer.py`` ``zero_spec_for``) keeps the optimizer state off.
 """
 
 from __future__ import annotations
@@ -29,6 +32,7 @@ class ParamInfo:
     init: str = "normal"           # normal | zeros | ones | small
     scale: Optional[float] = None  # stddev override; default 1/sqrt(fan_in)
     dtype: Optional[str] = None    # override model dtype (e.g. fp32 gates)
+    stacked: int = 0               # leading scan axes, prepended by :func:`stack`
 
 
 def _init_leaf(info: ParamInfo, key, dtype) -> jax.Array:
@@ -71,7 +75,8 @@ def specs(layout):
 def stack(n: int, layout):
     """Prepend a stacked-layers axis to every leaf of ``layout``."""
     return jax.tree.map(
-        lambda i: replace(i, shape=(n, *i.shape), spec=P(None, *i.spec)),
+        lambda i: replace(i, shape=(n, *i.shape), spec=P(None, *i.spec),
+                          stacked=i.stacked + 1),
         layout,
         is_leaf=_is_info,
     )

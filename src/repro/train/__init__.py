@@ -10,6 +10,7 @@ from .optimizer import (
     decompress_int8,
     init_opt_state,
     opt_state_specs,
+    zero_placement_tally,
     zero_spec_for,
 )
 from .step import (
@@ -29,5 +30,5 @@ __all__ = [
     "compress_int8",
     "config_digest", "decompress_int8", "init_opt_state", "init_train_state",
     "make_eval_step", "make_train_step", "opt_state_specs",
-    "run_with_restarts", "token_batch_from_bytes", "zero_spec_for",
+    "run_with_restarts", "token_batch_from_bytes", "zero_placement_tally", "zero_spec_for",
 ]

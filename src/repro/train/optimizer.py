@@ -5,10 +5,17 @@ lets us control sharding precisely.
 
 ZeRO-1 (default): the fp32 (mu, nu, master) states — 12 bytes/param, the
 dominant training memory — are sharded along the ``data`` axis on the first
-dimension whose size divides it and is not already model-sharded.  Under
-SPMD the optimizer update then runs data-parallel-sharded (each data shard
-updates its slice), which is exactly the ZeRO-1 compute/memory split; pjit
-inserts the (reduce-scatter + all-gather) pair where profitable.
+dimension whose size divides it and is not already model-sharded, skipping
+a layer-stacked leaf's scan axes (``ParamInfo.stacked``) unless no other
+dimension divides.  Under SPMD the optimizer update then runs
+data-parallel-sharded (each data shard updates its slice), which is exactly
+the ZeRO-1 compute/memory split; pjit inserts the (reduce-scatter +
+all-gather) pair where profitable.  Why not the scan axis: the backward
+layer scan produces one layer's gradient per iteration, and a state that
+gives each layer to one chip makes the partitioner all-reduce that layer's
+whole gradient inside the loop, with nothing to overlap it.  Split within
+each layer, the reduction becomes reduce-scatters that XLA overlaps with
+the weight-gradient matmuls.
 
 Gradient compression (pod axis / DCN): error-feedback int8 quantisation for
 the cross-pod gradient reduction, used by the explicit shard_map DP path in
@@ -19,7 +26,9 @@ holds.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -108,28 +117,73 @@ def adamw_update(grads, state, params, cfg: AdamWConfig):
 
 
 # ------------------------------------------------------------- ZeRO specs
-def zero_spec_for(param_spec: P, shape: tuple[int, ...], data_size: int, axis: str = "data") -> P:
-    """Add the ``data`` axis to the first unsharded, divisible dimension."""
+def zero_spec_for(
+    param_spec: P, shape: tuple[int, ...], data_size: int, axis: str = "data",
+    stacked: int = 0, axis_sizes: Optional[dict] = None,
+) -> P:
+    """Add the ``data`` axis to the first unsharded, divisible dimension.
+
+    For a leaf with ``stacked`` leading scan axes, the dimensions after them
+    come first: an unsharded one, else (given the mesh's ``axis_sizes``) one
+    whose mesh axes leave it divisible by ``data_size`` more, which then takes
+    ``data`` after them, as ``("model", "data")``.  The scan axis is the last
+    resort.  An unstacked leaf gets the first unsharded, divisible dimension.
+    """
     entries = list(param_spec) + [None] * (len(shape) - len(param_spec))
-    for i, (e, s) in enumerate(zip(entries, shape)):
-        if e is None and data_size > 0 and s % data_size == 0 and s >= data_size:
-            entries[i] = axis
-            return P(*entries)
+
+    def fits(i: int, held: tuple = ()) -> bool:
+        n = data_size * math.prod(axis_sizes[a] for a in held)
+        return n > 0 and shape[i] % n == 0 and shape[i] >= n
+
+    inner = range(stacked, len(shape))
+    free = [i for i in inner if entries[i] is None and fits(i)]
+    if stacked and not free and axis_sizes is not None:
+        for i in inner:
+            held = entries[i] if isinstance(entries[i], tuple) else (entries[i],)
+            if entries[i] is not None and fits(i, held):
+                entries[i] = (*held, axis)
+                return P(*entries)
+    free += [i for i in range(stacked) if entries[i] is None and fits(i)]
+    if free:
+        entries[free[0]] = axis
     return P(*entries)
+
+
+def _data_size(mesh, axis: str) -> int:
+    return mesh.shape[axis] if (mesh is not None and axis in mesh.axis_names) else 1
+
+
+def _zspec(info: PM.ParamInfo, mesh, axis: str) -> P:
+    sizes = dict(mesh.shape) if mesh is not None else None
+    return zero_spec_for(info.spec, info.shape, _data_size(mesh, axis), axis, info.stacked, sizes)
 
 
 def opt_state_specs(layout, mesh, cfg: AdamWConfig, axis: str = "data"):
     """Sharding-spec pytree matching ``init_opt_state``'s structure."""
-    data_size = mesh.shape[axis] if (mesh is not None and axis in mesh.axis_names) else 1
-
-    def zspec(info: PM.ParamInfo) -> P:
-        return zero_spec_for(info.spec, info.shape, data_size, axis)
-
-    sharded = jax.tree.map(zspec, layout, is_leaf=lambda x: isinstance(x, PM.ParamInfo))
+    sharded = jax.tree.map(lambda i: _zspec(i, mesh, axis), layout,
+                           is_leaf=lambda x: isinstance(x, PM.ParamInfo))
     state = {"mu": sharded, "nu": sharded, "count": P()}
     if cfg.master_fp32:
         state["master"] = sharded
     return state
+
+
+def zero_placement_tally(layout, mesh, cfg: AdamWConfig, axis: str = "data") -> dict:
+    """AdamW's fp32 state leaves (``mu``, ``nu``, ``master``) and their bytes
+    (all shards together) by where ``opt_state_specs`` puts the ``data``
+    axis: on a leaf's scan axis, on another of its axes, or nowhere (a mesh
+    without the axis, or of size 1, or no dimension divides)."""
+    data_size = _data_size(mesh, axis)
+    states = 3 if cfg.master_fp32 else 2
+    tally = {k: [0, 0] for k in ("scan_axis", "inner_axis", "replicated")}
+    for info in jax.tree.leaves(layout, is_leaf=lambda x: isinstance(x, PM.ParamInfo)):
+        on = [i for i, e in enumerate(_zspec(info, mesh, axis))
+              if e == axis or (isinstance(e, tuple) and axis in e)]
+        where = ("replicated" if data_size == 1 or not on
+                 else "scan_axis" if on[0] < info.stacked else "inner_axis")
+        tally[where][0] += states
+        tally[where][1] += states * 4 * math.prod(info.shape)
+    return {k: tuple(v) for k, v in tally.items()}
 
 
 # ---------------------------------------------------- gradient compression

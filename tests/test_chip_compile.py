@@ -11,6 +11,7 @@ chip.
 from __future__ import annotations
 
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -94,20 +95,62 @@ def test_data_parallel_step_compiles_on_four_chips(topo, one_chip_memory):
     assert "all-reduce" in hlo or "reduce-scatter" in hlo    # gradients cross chips
 
 
-def test_data_parallel_step_takes_the_kernel_on_four_chips(topo):
-    """``train.py --mesh data=4,model=1`` at the four-chip cell's 8 x 2048:
-    attention runs the Pallas kernel under shard_map, two rows per chip, and
-    the step fits one chip's memory."""
+@pytest.fixture(scope="module")
+def dp4_step(topo):
+    """``train.py --mesh data=4,model=1`` at the four-chip cell's 8 x 2048,
+    lowered and compiled once: the attention calls lowered per path, and
+    the compiled step."""
     mesh = make_test_mesh(data=4, model=1, devices=topo.devices)
     step = build_sharded_step(CFG, ShapeConfig("dp4", DP4_SEQ, DP4_BATCH, "train"), mesh)
     before = attention_path_tally()
     lowered = step.jitted.lower(*step.args)
     paths = {p: n - before[p] for p, n in attention_path_tally().items()}
+    return paths, lowered.compile()
+
+
+def test_data_parallel_step_takes_the_kernel_on_four_chips(dp4_step):
+    """Attention runs the Pallas kernel under shard_map, two rows per chip,
+    and the step fits one chip's memory."""
+    paths, compiled = dp4_step
     assert paths == {"pallas_flash": 1, "xla_blockwise": 0}
-    compiled = lowered.compile()
     mem = compiled.memory_analysis()                # per device
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     assert compiled.as_text().count("tpu_custom_call") >= 2    # forward and fused backward
+
+
+def _while_body_all_reduces(hlo: str) -> list[str]:
+    """The result types of the all-reduces in a while loop's body, or in a
+    computation that such a body calls (an all-reduce's result has its
+    operands' shapes)."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    todo, inside = re.findall(r"body=%([\w.-]+)", hlo), set()
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in inside:
+            inside.add(c)
+            todo += [r for line in comps[c] for r in re.findall(r"%([\w.-]+)", line)]
+    return [m.group(1) for c in inside for line in comps[c]
+            if (m := re.match(r"\s*(?:ROOT )?%\S+ = (.*?) all-reduce(?:-start)?\(", line))]
+
+
+def test_data_parallel_step_all_reduces_no_stacked_gradient_in_the_layer_scan(dp4_step):
+    """ZeRO keeps AdamW's state off the layer scan's axis, so inside the
+    scans no all-reduce carries a rank-3 operand (one layer's slice of a
+    stacked matrix gradient): those leave the backward scan as
+    reduce-scatters.  Sharded on the scan axis, each layer's whole gradient
+    was all-reduced on every chip inside the loop."""
+    _, compiled = dp4_step
+    hlo = compiled.as_text()
+    assert "while(" in hlo
+    inside = _while_body_all_reduces(hlo)
+    assert not [t for t in inside if re.search(r"\w\[\d+,\d+,\d+\]", t)], inside
 
 
 def test_chip_smoke_refuses_the_cpu():

@@ -11,12 +11,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS
 from repro.core import build_cluster
 from repro.data import TokenDatasetSpec, TokenLoader, materialize_token_dataset
 from repro.models import build_model
+from repro.models import params as PM
 from repro.train import (
     AdamWConfig,
     CheckpointManager,
@@ -27,7 +29,9 @@ from repro.train import (
     decompress_int8,
     init_train_state,
     make_train_step,
+    opt_state_specs,
     run_with_restarts,
+    zero_placement_tally,
     zero_spec_for,
 )
 
@@ -71,6 +75,63 @@ def test_zero_spec_adds_data_axis():
     # already-sharded dim skipped, non-divisible dim skipped
     spec = zero_spec_for(P("model", None), (8, 30), data_size=16)
     assert spec == P("model", None)
+
+
+DP4 = AbstractMesh((4, 1), ("data", "model"))     # the four-chip cell's mesh, described
+SIZES = dict(DP4.shape)
+
+
+@pytest.mark.parametrize("spec, shape, stacked, want", [
+    # a stacked leaf: the first unsharded, divisible dimension after the scan axis
+    (P(None, None, "model"), (24, 1024, 2816), 1, P(None, "data", "model")),
+    (P(None, "model", None), (24, 2816, 1024), 1, P(None, "model", "data")),
+    (P(None, None), (24, 1024), 1, P(None, "data")),
+    (P(None, None, None), (2, 4, 64), 2, P(None, None, "data")),    # a scan of scans
+    # none unsharded: after the axes that already hold a dimension
+    (P(None, "model"), (24, 1024), 1, P(None, ("model", "data"))),
+    # no other dimension divides: back to the scan axis
+    (P(None, None), (24, 6), 1, P("data", None)),
+    (P(None, "model"), (24, 6), 1, P("data", "model")),
+    # an unstacked leaf: the first unsharded, divisible dimension, as before
+    (P(None, "model"), (1024, 512), 0, P("data", "model")),
+    (P("model", None), (8, 30), 0, P("model", None)),
+    (P("model"), (1024,), 0, P("model")),
+])
+def test_zero_spec_keeps_state_off_the_scan_axis(spec, shape, stacked, want):
+    assert zero_spec_for(spec, shape, 4, stacked=stacked, axis_sizes=SIZES) == want
+
+
+def test_zero_specs_of_qwen_layers_leave_the_scan_axis():
+    """At data=4, no layer-stacked leaf of qwen1.5-0.5b has its AdamW state
+    sharded on axis 0 (the layer scan's), and the tally says so."""
+    layout = build_model(ARCHS["qwen1.5-0.5b"], mesh=None, model_axis=1).layout()
+    specs = opt_state_specs(layout, DP4, AdamWConfig())
+    for state in ("mu", "nu", "master"):
+        layers = jax.tree.leaves(specs[state]["layers"], is_leaf=lambda x: isinstance(x, P))
+        assert len(layers) == 12
+        assert all(s[0] is None for s in layers), layers
+    n_bytes = 3 * 4 * PM.param_count(layout)
+    assert zero_placement_tally(layout, DP4, AdamWConfig()) == {
+        "scan_axis": (0, 0), "inner_axis": (42, n_bytes), "replicated": (0, 0)}
+
+
+@pytest.mark.parametrize("mesh, master, want", [
+    (DP4, True, {"scan_axis": (3, 288), "inner_axis": (6, 8448), "replicated": (3, 180)}),
+    (DP4, False, {"scan_axis": (2, 192), "inner_axis": (4, 5632), "replicated": (2, 120)}),
+    (AbstractMesh((2, 1), ("data", "model")), True,
+     {"scan_axis": (0, 0), "inner_axis": (9, 8736), "replicated": (3, 180)}),
+    (None, True, {"scan_axis": (0, 0), "inner_axis": (0, 0), "replicated": (12, 8916)}),
+])
+def test_zero_placement_tally(mesh, master, want):
+    """Leaves of mu, nu (and master) and their fp32 bytes, by where ZeRO put them."""
+    layout = {
+        "embed": PM.ParamInfo((64, 8)),                  # axis 0 divides
+        "odd": PM.ParamInfo((3, 5)),                     # nothing divides 4 or 2
+        "layers": PM.stack(4, {"w": PM.ParamInfo((8, 6)),      # axis 1 divides
+                               "b": PM.ParamInfo((6,))}),     # only the scan axis does at 4
+    }
+    assert layout["layers"]["b"].stacked == 1
+    assert zero_placement_tally(layout, mesh, AdamWConfig(master_fp32=master)) == want
 
 
 def test_int8_error_feedback_roundtrip():
@@ -260,7 +321,7 @@ def mesh_training(tmp_path_factory):
     proc = subprocess.run([sys.executable, "-c", _MESH_TRAIN], capture_output=True, text=True,
                           timeout=600, env=env)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return {**json.loads(proc.stdout.strip().splitlines()[-1]), "stdout": proc.stdout}
 
 
 def test_trainer_on_a_mesh_matches_one_device(mesh_training):
@@ -272,6 +333,19 @@ def test_trainer_on_a_mesh_matches_one_device(mesh_training):
     np.testing.assert_allclose(four, one, rtol=1e-5)
     assert mesh_training["four_placed"]["devices"] == [4]
     assert mesh_training["four_placed"]["zero"] > 0
+
+
+def test_trainer_on_a_mesh_prints_where_zero_put_the_state(mesh_training):
+    """The first step's line under ``--mesh`` ends with the optimizer-state
+    tally (leaves/bytes per placement); without a mesh it has none."""
+    firsts = [l for l in mesh_training["stdout"].splitlines() if l.startswith("step     0 ")]
+    assert len(firsts) == 3                  # one device, four, four until the failed read
+    layout = build_model(ARCHS["qwen1.5-0.5b"].smoke(), mesh=None, model_axis=1).layout()
+    tally = zero_placement_tally(layout, DP4, AdamWConfig())
+    want = " zero " + " ".join(f"{k}={n}/{b}B" for k, (n, b) in tally.items())
+    assert " zero " not in firsts[0]
+    assert firsts[1].endswith(want) and firsts[2].endswith(want)
+    assert " zero scan_axis=0/0B inner_axis=" in want
 
 
 def test_trainer_on_a_mesh_resumes_from_its_checkpoint(mesh_training):
